@@ -159,8 +159,10 @@ def _spawn(worker: str, *, extra_flags=(), cache_dir: str | None = None,
 
     The child's XLA_FLAGS are fully replaced (forced device count + the
     candidate set) so measurements are comparable no matter what the
-    parent inherited."""
-    env = dict(os.environ)
+    parent inherited.  The child measures that forced host topology, so
+    it is pinned to the CPU: it never asks for a chip the parent (which
+    has touched JAX) may hold."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = " ".join(
         [f"--xla_force_host_platform_device_count={DEV_COUNT}"]
         + list(extra_flags))

@@ -2,9 +2,9 @@
 
 The paper's metric is column reads; on TPU the analogue is bit-planes
 visited.  We report, per workload: planes visited / 32 (skip fraction from
-the leading-uniform certification) and wall time of the interpret-mode
-kernel vs the jnp oracle (CPU container: relative numbers only — the Pallas
-path is TPU-targeted).
+the leading-uniform certification) and wall time of the kernel in the mode
+the platform resolves (:mod:`repro.kernels.dispatch`: compiled on TPU,
+interpreted elsewhere; a CPU wall time is not a speed).
 """
 
 from __future__ import annotations
@@ -46,7 +46,7 @@ def run(report):
     for name, arr in cases.items():
         x = jnp.asarray(arr)
         (t, visited), us = _timed(
-            lambda v: threshold_pallas(v, 8, interpret=True), x)
+            lambda v: threshold_pallas(v, 8), x)
         tr = threshold_ref(x, 8)
         ok = np.array_equal(np.asarray(t), np.asarray(tr))
         report(
@@ -63,8 +63,7 @@ def run(report):
     # has structure; the network wins on adversarial/uniform data.
     x = np.stack([make_dataset("mapreduce", 1024, 32, seed=s).astype(np.uint32)
                   for s in (1, 2)])
-    (srt,), us = _timed(lambda a: (bitonic_sort(a, use_pallas=True,
-                                                interpret=True),),
+    (srt,), us = _timed(lambda a: (bitonic_sort(a, use_pallas=True),),
                         jnp.asarray(x))
     ok = all(np.array_equal(np.asarray(srt[i]), np.sort(x[i])) for i in range(2))
     report(name="kernel/bitonic_sort/mapreduce_1024", us_per_call=us,
@@ -76,8 +75,8 @@ def run(report):
         v = np.stack([make_dataset(ds, 128, 32, seed=s).astype(np.uint32)
                       for s in (1, 2)])
         (vals, order, crs, cyc), us = _timed(
-            lambda a: colskip_sort_batched(a, 32, 2, use_pallas=True,
-                                           interpret=True), jnp.asarray(v))
+            lambda a: colskip_sort_batched(a, 32, 2, use_pallas=True),
+            jnp.asarray(v))
         sorted_ok = all(np.array_equal(np.asarray(vals[i]), np.sort(v[i]))
                         for i in range(2))
         report(
@@ -89,16 +88,17 @@ def run(report):
         )
 
     # --- colskip kernel: lane-packed vs dense mask carriers --------------
-    # same Pallas (interpret) kernel body, packed vs dense §III machine;
-    # telemetry must agree bit-exactly while the packed path runs faster
+    # packed vs dense §III machine, each where the platform runs it (the
+    # dense carrier has no compiled kernel, so on TPU it takes the XLA
+    # reference); telemetry must agree bit-exactly
     # (the headline 1024-wide numbers live in benchmarks/packed_bench.py)
     v = np.stack([make_dataset("mapreduce", 128, 32, seed=s).astype(np.uint32)
                   for s in (1, 2)])
     vj = jnp.asarray(v)
     (out_p), us_p = _timed(lambda a: colskip_sort_batched(
-        a, 32, 2, use_pallas=True, interpret=True, packed=True), vj)
+        a, 32, 2, packed=True), vj)
     (out_d), us_d = _timed(lambda a: colskip_sort_batched(
-        a, 32, 2, use_pallas=True, interpret=True, packed=False), vj)
+        a, 32, 2, packed=False), vj)
     same = all(np.array_equal(np.asarray(a), np.asarray(b))
                for a, b in zip(out_p, out_d))
     report(name="kernel/colskip_sort/packed_vs_dense", us_per_call=us_p,

@@ -13,13 +13,7 @@ layer that puts those collectives to work on an actual device mesh:
     axis (``ppermute`` ring);
   * :mod:`repro.dist.bankmesh`  — ``MeshBankPool``: the sortserve bank pool
     with shard groups mapped onto mesh devices, one ``psum`` per bit plane.
-
-Importing the package installs the jax forward-compat shims
-(:mod:`repro.dist._jaxcompat`) so all of the above runs on the container's
-jax as well as on current releases.
 """
-
-from . import _jaxcompat  # noqa: F401  (side effect: installs jax shims)
 
 from .compress import ef_topk_psum, ef_topk_psum_auto
 from .sharding import act_specs, cache_spec, dp_axes, param_specs

@@ -10,8 +10,9 @@ column-skipping sort runs with the manager's OR-gates as collectives:
     saw-a-1 / saw-a-0 predicate bits of every bank, stacked and reduced
     together (the ``en_sync`` broadcast of the manager circuit);
   * state-table liveness (SL) is a ``psum`` of per-entry local hit bits;
-  * the duplicate drain is bank-major: an ``all_gather`` of per-bank survivor
-    counts gives every bank the exclusive prefix it needs to place its rows.
+  * the duplicate drain is bank-major: one gather of per-bank survivor
+    counts (a psum of one-hot rows) gives every bank the exclusive prefix it
+    needs to place its rows.
 
 Because §V.C's result — bank management never changes the cycle count — holds
 for the collective realization too, :class:`MeshBankPool` telemetry is
@@ -53,8 +54,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.sortserve.scheduler import BankPool
-
-from ._jaxcompat import shard_map
 
 __all__ = ["MeshBankPool", "collective_rounds", "colskip_sort_mesh",
            "make_bank_mesh", "sharded_tile_fn", "topology_fingerprint"]
@@ -110,7 +109,7 @@ def collective_rounds(w: int, stop: int, fuse: int = 1) -> dict:
 
     Per §IV iteration: one SL-gate round (load), ``ceil(w / fuse)``
     traverse rounds (each fused block is a single psum), and one drain
-    ``all_gather``; plus the 2 assembly psums per tile.  ``planes`` is the
+    gather; plus the 2 assembly psums per tile.  ``planes`` is the
     plane-traversal count the unfused path would pay one round each for —
     ``rounds / planes`` is the mesh-side CR analogue the ``collectives``
     telemetry family reports.
@@ -152,19 +151,26 @@ def _colskip_tile_local(u_local, *, w: int, k: int, stop: int, axis_name,
 
     def drain_counts(m_local):
         """Bank-major drain: every bank learns all survivor counts via one
-        all_gather and takes its exclusive prefix (gather order over the
-        flattened axes matches the flat ``axis_index`` above)."""
-        m_all = jax.lax.all_gather(m_local, axes)                  # (C, TB)
-        before = jnp.where(jnp.arange(nbanks)[:, None] < bank,
-                           m_all, 0).sum(0)                        # (TB,)
+        gather and takes its exclusive prefix.  The gather is a psum of
+        one-hot rows (indexed by the flat ``axis_index`` above), so the
+        counts come back replicated and the per-row drain state stays
+        replicated too."""
+        banks = jnp.arange(nbanks).reshape((-1,) + (1,) * m_local.ndim)
+        m_all = jax.lax.psum(jnp.where(banks == bank, m_local, 0),
+                             axes)                             # (C, TB, 1)
+        before = jnp.where(banks < bank, m_all, 0).sum(0)      # (TB, 1)
         return m_all.sum(0), before
+
+    def vary(x):
+        """The masks start replicated (zeros) and end varying per bank."""
+        return jax.lax.pcast(x, axes, to="varying")
 
     # the machine's mask carriers may be lane-packed; the manager gates above
     # see only predicate stacks and survivor counts either way, so the psum
     # pattern (one collective per fused block) is representation-invariant
     sorted_mask, out_pos, crs, drains = colskip_machine(
         u, w, k, stop, or_any=or_any, drain_counts=drain_counts,
-        packed=packed, fuse=fuse)
+        packed=packed, fuse=fuse, vary=vary)
 
     # output select: each bank scatters its drained rows into the global
     # (TB, stop) result; a psum assembles + broadcasts it (zeros elsewhere)
@@ -205,8 +211,8 @@ def sharded_tile_fn(mesh, axis_name, w: int, k: int, stop: int,
         axes = _axes_tuple(axis_name)
         body = functools.partial(_colskip_tile_local, w=w, k=k, stop=stop,
                                  axis_name=axes, packed=packed, fuse=fuse)
-        fn = shard_map(body, mesh=mesh, in_specs=P(None, axes),
-                       out_specs=(P(), P(), P(), P()))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P(None, axes),
+                           out_specs=(P(), P(), P(), P()))
         _SHARDED_FNS[key] = fn
     return fn
 

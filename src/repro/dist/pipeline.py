@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._jaxcompat import shard_map
-
 __all__ = ["make_pipelined_fn"]
 
 
@@ -46,7 +44,9 @@ def make_pipelined_fn(mesh, block_fn, axis_name: str):
             outs = outs.at[idx].set(jnp.where(write, y, outs[idx]))
             return (jax.lax.ppermute(y, axis_name, perm), outs), None
 
-        carry0 = (jnp.zeros(xs.shape[1:], xs.dtype), jnp.zeros_like(xs))
+        # the ring buffer and the outputs vary per stage from the first step
+        carry0 = jax.lax.pcast((jnp.zeros(xs.shape[1:], xs.dtype),
+                                jnp.zeros_like(xs)), axis_name, to="varying")
         (_, outs), _ = jax.lax.scan(step, carry0,
                                     jnp.arange(m + n_stages - 1))
         # only the last stage holds results; psum broadcasts (others are 0)
@@ -54,5 +54,5 @@ def make_pipelined_fn(mesh, block_fn, axis_name: str):
         return jax.lax.psum(jnp.where(last, outs, jnp.zeros_like(outs)),
                             axis_name)
 
-    return shard_map(stage_local, mesh=mesh, in_specs=(P(axis_name), P()),
-                     out_specs=P())
+    return jax.shard_map(stage_local, mesh=mesh,
+                         in_specs=(P(axis_name), P()), out_specs=P())

@@ -31,8 +31,6 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import _jaxcompat  # noqa: F401  (jax shims; keeps this module leaf)
-
 __all__ = ["act_specs", "cache_spec", "dp_axes", "param_specs"]
 
 DEFAULT_AXIS_SIZES = {"model": 16, "data": 16}

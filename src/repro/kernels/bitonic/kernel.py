@@ -9,12 +9,12 @@ is compared against in benchmarks/kernel_bench.py.
 
 Passes are unrolled at trace time (N static, power of two): stage k doubles
 the sorted-run length, substage j exchanges lane i with lane i^j in the
-direction given by bit k of i.  The exchange is expressed as a reshape to
-``(TB, N/2j, 2, j)`` plus elementwise min/max — lane i's partner i^j is the
-other element of axis 2 — rather than a ``take_along_axis`` gather: the
-pairing is compile-time regular, reshapes are free on the VPU, and the
-gather formulation made XLA's CPU backend (used for interpret-mode tests)
-compile the unrolled network pathologically slowly (minutes per shape).
+direction given by bit k of i.  Lane i's partner i^j is fetched by two
+lane rotations (``pltpu.roll`` by j and by N-j) and a select on bit j of an
+iota — lane rotations are what the TPU's cross-lane unit does natively,
+where a reshape to ``(TB, N/2j, 2, j)`` is refused by Mosaic, and a
+``take_along_axis`` gather made XLA's CPU backend (used for interpret-mode
+tests) compile the unrolled network pathologically slowly.
 """
 
 from __future__ import annotations
@@ -24,34 +24,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..dispatch import resolve_interpret
 
 
 def _bitonic_kernel(x_ref, out_ref):
     u = x_ref[...]                                # (TB, N) uint32
     tb, n = u.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, n), 1)
     k = 2
     while k <= n:
         j = k // 2
         while j >= 1:
-            # lane i = q*2j + s*j + t pairs with i^j: axis 2 below is s
-            m = n // (2 * j)
-            v = u.reshape(tb, m, 2, j)
-            a, b = v[:, :, 0, :], v[:, :, 1, :]
-            mn, mx = jnp.minimum(a, b), jnp.maximum(a, b)
-            # direction bit: k >= 2j, so i & k depends only on the block q
-            q = jax.lax.broadcasted_iota(jnp.int32, (1, m, 1), 1)
-            up = (q * (2 * j)) & k == 0           # ascending region
-            lo = jnp.where(up, mn, mx)
-            hi = jnp.where(up, mx, mn)
-            u = jnp.stack([lo, hi], axis=2).reshape(tb, n)
+            lower = lane & j == 0                 # lane i pairs with i + j
+            # one of the two rotations by j brings lane i^j to lane i;
+            # rotating the lane iota too says which, whatever the direction
+            fwd = pltpu.roll(lane, j, 1) == lane ^ j
+            partner = jnp.where(fwd, pltpu.roll(u, j, 1),
+                                pltpu.roll(u, n - j, 1))
+            up = lane & k == 0                    # ascending region
+            # keep the min where lower == up, else the max (Mosaic has no
+            # unsigned min/max; an unsigned compare and a select do it)
+            u = jnp.where((u < partner) == (lower == up), u, partner)
             j //= 2
         k *= 2
     out_ref[...] = u
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "interpret"))
-def sort_pallas(x: jax.Array, tb: int = 8, interpret: bool = True):
-    """Ascending sort of each row of ``x`` (B, N) uint32; N a power of two."""
+def sort_pallas(x: jax.Array, tb: int = 8, interpret: bool | None = None):
+    """Ascending sort of each row of ``x`` (B, N) uint32; N a power of two.
+    ``interpret=None`` resolves from the platform (compiled on TPU)."""
+    interpret = resolve_interpret(interpret)
     b, n = x.shape
     assert n & (n - 1) == 0, f"bitonic needs power-of-two N, got {n}"
     bp = (b + tb - 1) // tb * tb

@@ -1,14 +1,12 @@
 """Public op over the bitonic kernel (TPU -> Pallas, else oracle)."""
 
-import jax
-
+from ..dispatch import resolve
 from . import kernel as _k
 from . import ref as _ref
 
 
 def bitonic_sort(x, *, use_pallas=None, interpret=None):
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu" or bool(interpret)
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if use_pallas:
-        return _k.sort_pallas(x, interpret=True if interpret is None else interpret)
+        return _k.sort_pallas(x, interpret=interpret)
     return _ref.sort_ref(x)

@@ -2,10 +2,24 @@
 
 from __future__ import annotations
 
-import jax
-
+from ..dispatch import impl_name, resolve
 from . import kernel as _k
 from . import ref as _ref
+
+
+def resolve_colskip(use_pallas: bool | None = None,
+                    interpret: bool | None = None,
+                    packed: bool = True) -> tuple[bool, bool, str]:
+    """``(use_pallas, interpret, impl)`` for a colskip call.
+
+    The platform rule of :func:`repro.kernels.dispatch.resolve`, except that
+    the dense carrier has no compiled kernel: left to the default, it runs
+    on the XLA reference where the kernel would be compiled."""
+    if use_pallas is None and not packed and resolve(None, interpret) == (
+            True, False):
+        use_pallas = False
+    use_pallas, interpret = resolve(use_pallas, interpret)
+    return use_pallas, interpret, impl_name(use_pallas, interpret)
 
 
 def colskip_sort_batched(x, w: int = 32, k: int = 2, *,
@@ -22,10 +36,8 @@ def colskip_sort_batched(x, w: int = 32, k: int = 2, *,
     ``packed=False`` selects the dense-boolean machine (equivalence
     baseline) instead of the lane-packed hot path.
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu" or bool(interpret)
+    use_pallas, interpret, _ = resolve_colskip(use_pallas, interpret, packed)
     if use_pallas:
-        return _k.sort_pallas(x, w, k,
-                              interpret=True if interpret is None else interpret,
+        return _k.sort_pallas(x, w, k, interpret=interpret,
                               stop_after=stop_after, packed=packed)
     return _ref.sort_ref(x, w, k, stop_after=stop_after, packed=packed)

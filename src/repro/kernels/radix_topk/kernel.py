@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..dispatch import resolve_interpret
+
 DEFAULT_TB = 8
 MAX_N = 16384  # per-block trailing width; wider inputs are banked in ops.py
 
@@ -45,34 +47,56 @@ def _to_sortable(x):
     return b ^ mask
 
 
+def _or_and(u):
+    """Per-row bitwise OR and AND of a (TB, N) uint32 tile, (TB, 1) each.
+
+    Mosaic has no unsigned or bitwise reductions, so lane halves are
+    folded with ``|`` / ``&`` while they stay 128-aligned, and each bit
+    plane of what is left is one int32 max (OR) / min (AND)."""
+    o = a = u
+    while o.shape[1] % 256 == 0:
+        h = o.shape[1] // 2
+        o, a = o[:, :h] | o[:, h:], a[:, :h] & a[:, h:]
+    v_or = v_and = jnp.uint32(0)
+    for p in range(32):
+        o_p = jnp.max(_plane(o, p), 1, keepdims=True).astype(jnp.uint32)
+        a_p = jnp.min(_plane(a, p), 1, keepdims=True).astype(jnp.uint32)
+        v_or, v_and = v_or | o_p << p, v_and | a_p << p
+    return v_or, v_and
+
+
+def _plane(u, p: int):
+    """Bit ``p`` of every element, as int32 0/1."""
+    return ((u >> p) & 1).astype(jnp.int32)
+
+
 def _threshold_kernel(k: int, x_ref, thresh_ref, visited_ref):
     u = _to_sortable(x_ref[...])                       # (TB, N) uint32
     tb = u.shape[0]
 
     # --- certify leading uniform planes (the skippable columns) ----------
-    u_or = jax.lax.reduce(u, jnp.uint32(0), jax.lax.bitwise_or, (1,))      # (TB,)
-    u_and = jax.lax.reduce(u, jnp.uint32(0xFFFFFFFF), jax.lax.bitwise_and, (1,))
+    u_or, u_and = _or_and(u)                           # (TB, 1) each
     mixed = u_or ^ u_and                               # per-row discriminating planes
-    tile_mixed = jax.lax.reduce(mixed, jnp.uint32(0), jax.lax.bitwise_or, (0,))
-    planes = jnp.arange(32, dtype=jnp.int32)
-    s_top = jnp.max(jnp.where((tile_mixed >> planes.astype(jnp.uint32)) & 1 > 0,
-                              planes, -1))             # () int32, -1 if constant
+    s_top = jnp.int32(-1)                              # -1 if constant
+    for p in range(32):                                # highest mixed plane
+        s_top = jnp.where(jnp.max(_plane(mixed, p)) > 0, p, s_top)
 
     # prefix pre-load: bits above s_top are uniform per row -> take from AND
     hi_of = lambda p: ~((jnp.uint32(1) << p.astype(jnp.uint32) << 1) - 1)
     hi0 = jnp.where(s_top >= 31, jnp.uint32(0),
                     jnp.where(s_top < 0, jnp.uint32(0xFFFFFFFF),
                               hi_of(jnp.maximum(s_top, 0))))
-    prefix0 = u_and & hi0                              # (TB,)
-    need0 = jnp.full((tb,), k, jnp.int32)
+    prefix0 = u_and & hi0                              # (TB, 1)
+    need0 = jnp.full((tb, 1), k, jnp.int32)
 
     def body(j, carry):
         prefix, need = carry
         plane = (s_top - j).astype(jnp.uint32)         # s_top, s_top-1, ..., 0
         bit = jnp.uint32(1) << plane
         hi_mask = ~((bit << jnp.uint32(1)) - jnp.uint32(1))
-        cand = (u & hi_mask) == prefix[:, None]
-        c1 = jnp.sum(cand & ((u & bit) != 0), axis=1).astype(jnp.int32)
+        cand = (u & hi_mask) == prefix
+        c1 = jnp.sum((cand & ((u & bit) != 0)).astype(jnp.int32), axis=1,
+                     keepdims=True)
         take_hi = c1 >= need
         prefix = jnp.where(take_hi, prefix | bit, prefix)
         need = jnp.where(take_hi, need, need - c1)
@@ -80,17 +104,19 @@ def _threshold_kernel(k: int, x_ref, thresh_ref, visited_ref):
 
     n_planes = jnp.maximum(s_top + 1, 0)
     prefix, _ = jax.lax.fori_loop(0, n_planes, body, (prefix0, need0))
-    thresh_ref[...] = prefix[:, None]
+    thresh_ref[...] = prefix
     visited_ref[...] = jnp.full((tb, 1), n_planes, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tb", "interpret"))
 def threshold_pallas(x: jax.Array, k: int, tb: int = DEFAULT_TB,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Per-row k-th-largest threshold (sortable-uint32) + planes-visited.
 
     ``x``: (B, N) float32, N <= MAX_N.  B is padded to a multiple of ``tb``.
+    ``interpret=None`` resolves from the platform (compiled on TPU).
     """
+    interpret = resolve_interpret(interpret)
     b, n = x.shape
     if n > MAX_N:
         raise ValueError(f"N={n} > MAX_N={MAX_N}; bank at the ops level")

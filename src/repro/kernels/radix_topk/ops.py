@@ -4,9 +4,9 @@
 gradient compression).  Dispatch policy:
 
   * On TPU the Pallas kernel computes thresholds (compiled, VMEM-tiled);
-    everywhere else (this CPU container, and any backend without Mosaic) the
-    pure-jnp oracle path is used — the algorithm is identical, so dry-run
-    cost analysis remains representative.
+    everywhere else the pure-jnp oracle path is used — the algorithm is
+    identical, so dry-run cost analysis remains representative
+    (:mod:`repro.kernels.dispatch` resolves this once, from the platform).
   * Rows wider than ``kernel.MAX_N`` are split into *banks*; per-bank top-k
     candidates are concatenated and reduced by a second pass — exactly the
     paper's multi-bank management (sub-sorters + manager select), and exact
@@ -26,21 +26,17 @@ from repro.core.topk import (
     kth_largest_sortable,
     to_sortable_uint,
 )
+from ..dispatch import resolve
 from . import kernel as _k
-
-
-def _default_use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def radix_topk_threshold(x: jax.Array, k: int, *, use_pallas: bool | None = None,
                          interpret: bool | None = None) -> jax.Array:
     """Sortable-uint32 threshold (k-th largest) per row of ``x`` (B, N)."""
-    if use_pallas is None:
-        use_pallas = _default_use_pallas() or interpret
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if use_pallas:
-        interp = True if interpret is None else interpret
-        t, _ = _k.threshold_pallas(x.astype(jnp.float32), k, interpret=interp)
+        t, _ = _k.threshold_pallas(x.astype(jnp.float32), k,
+                                   interpret=interpret)
         return t
     return kth_largest_sortable(to_sortable_uint(x.astype(jnp.float32)), k)
 

@@ -5,8 +5,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import _jaxcompat  # noqa: F401  (axis_types shim on jax 0.4.x)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""

@@ -26,6 +26,7 @@ from repro.sortserve import (
     encode_payload,
     solve_numpy,
 )
+from repro.sortserve.backends import EXECUTOR_CACHE, checkout_cache_dir
 from repro.sortserve.request import decode_values
 
 
@@ -179,7 +180,9 @@ def main(argv=None):
                          "collectives.rounds changes")
     ap.add_argument("--compile-cache", default="", dest="compile_cache",
                     help="persistent jax compilation-cache directory: AOT "
-                         "executables compiled once survive process restarts")
+                         "executables compiled once survive process "
+                         "restarts (default: JAX_COMPILATION_CACHE_DIR if "
+                         "set, else <checkout>/.jax_cache)")
     ap.add_argument("--hw-profile", default="", dest="hw_profile",
                     help="tuned-hardware profile JSON from scripts/hw_tune.py "
                          "(XLA flags + compile cache + routing/calibration "
@@ -193,7 +196,9 @@ def main(argv=None):
                     help="colskip engine: Pallas kernel vs jitted reference "
                          "(auto = Pallas on TPU)")
     ap.add_argument("--interpret", **tri,
-                    help="Pallas interpret mode (auto = interpret off-TPU)")
+                    help="Pallas interpret mode (auto = compiled on TPU; "
+                         "off TPU the jitted reference runs unless "
+                         "--use_pallas on, which then interprets)")
     ap.add_argument("--dense", action="store_true",
                     help="dense-boolean §III machine instead of the "
                          "lane-packed hot path (equivalence baseline)")
@@ -247,7 +252,8 @@ def main(argv=None):
     # XLA flags (e.g. --xla_force_host_platform_device_count) are read once
     profile = apply_hw_profile(args.hw_profile) if args.hw_profile else None
     compile_cache = args.compile_cache or (
-        profile.get("compile_cache") if profile else None) or None
+        profile.get("compile_cache") if profile else None) or \
+        checkout_cache_dir()
 
     backends = tuple(s for s in args.backends.split(",") if s)
     if args.mesh_hosts > 1 and not args.mesh:
@@ -398,10 +404,11 @@ def main(argv=None):
         print(f"collectives: {coll['rounds']} rounds / {coll['planes']} "
               f"planes (round CR {coll['round_cr']:.2f}x, fuse={args.fuse})  "
               f"prefetch {coll['prefetch_hits']}/{coll['prefetch_staged']}")
-    if compile_cache:
+    if EXECUTOR_CACHE.persistent_dir:
         ec = telem["executor_cache"]
         print(f"persistent cache: {ec['persistent_hits']} hits / "
-              f"{ec['persistent_misses']} misses -> {compile_cache}")
+              f"{ec['persistent_misses']} misses -> "
+              f"{EXECUTOR_CACHE.persistent_dir}")
     print(f"scheduler drains: {telem['scheduler']['drains']}  "
           f"oversized waves: {telem['scheduler']['oversized_waves']}  "
           f"mid-wave admissions: {telem['scheduler']['mid_wave_admissions']}")

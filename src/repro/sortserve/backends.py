@@ -44,8 +44,10 @@ tracing/lowering entirely and goes straight to the warm executable.
 
 from __future__ import annotations
 
+import os
 import threading
 from dataclasses import dataclass, field
+from pathlib import Path
 
 import numpy as np
 
@@ -93,22 +95,34 @@ class ExecutorCache:
         self.persistent_dir: str | None = None
         self._listener_installed = False
 
-    def enable_persistent(self, cache_dir) -> bool:
+    def enable_persistent(self, cache_dir=None) -> bool:
         """Wire the JAX persistent compilation cache under this cache.
 
         Every AOT build (``jit().lower().compile()``) then writes its
-        serialized executable to ``cache_dir``; a fresh process pointed at
-        the same directory deserializes instead of compiling, so warm
-        starts survive restarts.  Returns True when the cache (and its
-        hit/miss event stream) is active on this jax.  Idempotent —
+        serialized executable to the cache directory; a fresh process
+        pointed at the same directory deserializes instead of compiling,
+        so warm starts survive restarts.  ``JAX_COMPILATION_CACHE_DIR``,
+        when set, is that directory and no other is set here; otherwise
+        ``cache_dir`` is.  Serving executors are small and fast to
+        compile, so both entry thresholds drop to "cache everything", and
+        a listener counts the ``cache_hits`` / ``cache_misses`` events.
+        Returns False when there is no directory to use.  Idempotent —
         re-enabling only repoints the directory."""
-        from repro.dist._jaxcompat import enable_persistent_compilation_cache
-        listener = None if self._listener_installed else self._on_cache_event
-        ok = enable_persistent_compilation_cache(cache_dir, listener)
-        if ok:
+        import jax
+        from jax._src import monitoring
+
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+        if not cache_dir:
+            return False
+        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+            jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        if not self._listener_installed:
+            monitoring.register_event_listener(self._on_cache_event)
             self._listener_installed = True
-            self.persistent_dir = str(cache_dir)
-        return ok
+        self.persistent_dir = str(cache_dir)
+        return True
 
     def _on_cache_event(self, event, **kw):
         # jax monitoring stream: one event per compilation-cache lookup
@@ -172,6 +186,13 @@ class ExecutorCache:
 EXECUTOR_CACHE = ExecutorCache()
 
 
+def checkout_cache_dir() -> str:
+    """The entry points' compile-cache directory when
+    ``JAX_COMPILATION_CACHE_DIR`` is not set: ``<checkout>/.jax_cache``,
+    a fixed path (the path is part of the cache key), git-ignored."""
+    return str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
 def _aot_compile(fn, *shapes, donate_first: bool = True):
     """``jax.jit(fn).lower(*shapes).compile()`` with the first buffer donated.
 
@@ -183,8 +204,8 @@ def _aot_compile(fn, *shapes, donate_first: bool = True):
 
 
 def _compiled_colskip(b: int, n: int, w: int, state_k: int,
-                      stop: int | None, use_pallas: bool | None,
-                      interpret: bool | None, packed: bool):
+                      stop: int | None, use_pallas: bool,
+                      interpret: bool, packed: bool):
     """Warm executor for one colskip tile signature."""
     import jax
     import jax.numpy as jnp
@@ -319,10 +340,12 @@ class ColskipBackend(Backend):
     def __init__(self, w: int = 32, state_k: int = 2,
                  use_pallas: bool | None = None,
                  interpret: bool | None = None, packed: bool = True):
+        from repro.kernels.colskip.ops import resolve_colskip
         self.w = w
         self.state_k = state_k
-        self.use_pallas = use_pallas
-        self.interpret = interpret
+        # resolved once from the platform: compiled Pallas on TPU
+        self.use_pallas, self.interpret, self.impl = resolve_colskip(
+            use_pallas, interpret, packed)
         self.packed = packed
 
     def run(self, tile: Tile) -> TileResult:
@@ -340,6 +363,7 @@ class ColskipBackend(Backend):
                           self.name, meta={"w": self.w, "state_k": self.state_k,
                                            "stop_after": stop,
                                            "packed": self.packed,
+                                           "impl": self.impl,
                                            "exec_warm": warm})
 
     def warm(self, b: int, n: int, op: str, k: int | None) -> bool:
@@ -408,12 +432,14 @@ class ShardedColskipBackend(Backend):
         from repro.dist.bankmesh import sharded_tile_fn
         # AOT-compiled through the executor cache (like the local
         # backends), so a cold mesh tile is visible as a cache miss —
-        # the engine's warm-only EMA gate depends on that
+        # the engine's warm-only EMA gate depends on that.  The tile is
+        # not donated: its bank shards cannot alias the replicated outputs
+        # (the TPU compiler refuses the alias)
         return EXECUTOR_CACHE.get(self._mesh_key(b, n, stop_eff),
                                   lambda: _aot_compile(
             sharded_tile_fn(self.mesh, self.axis_name, self.w,
                             self.state_k, stop_eff, self.packed, self.fuse),
-            jax.ShapeDtypeStruct((b, n), jnp.uint32)))
+            jax.ShapeDtypeStruct((b, n), jnp.uint32), donate_first=False))
 
     def prefetch(self, tile: Tile) -> bool:
         """Stage the next tile's device transfer (double buffering).
@@ -468,7 +494,7 @@ class ShardedColskipBackend(Backend):
                           meta={"w": self.w, "state_k": self.state_k,
                                 "stop_after": stop, "mesh_banks": banks_used,
                                 "packed": self.packed, "exec_warm": warm,
-                                "fuse": self.fuse,
+                                "impl": "xla", "fuse": self.fuse,
                                 "prefetch_hit": prefetch_hit, **coll})
 
     def warm(self, b: int, n: int, op: str, k: int | None) -> bool:

@@ -128,6 +128,7 @@ class EngineConfig:
                                     # the mesh path (results fuse-invariant)
     compile_cache: str | None = None  # persistent jax compilation-cache dir
                                       # under the executor cache; None off
+                                      # unless JAX_COMPILATION_CACHE_DIR
     cache_size: int = 1024          # result-cache entries (0 disables)
     use_pallas: bool | None = None  # colskip engine: Pallas kernel vs ref
     interpret: bool | None = None   # Pallas interpret mode (None = auto)
@@ -193,11 +194,11 @@ class SortServeEngine:
             kwargs[sim].setdefault("packed", self.config.packed)
         kwargs["colskip"].setdefault("use_pallas", self.config.use_pallas)
         kwargs["colskip"].setdefault("interpret", self.config.interpret)
-        if self.config.compile_cache:
-            # persistent compilation cache under the executor cache: every
-            # AOT build below lands on disk, and a fresh process pointed at
-            # the same directory deserializes instead of compiling
-            EXECUTOR_CACHE.enable_persistent(self.config.compile_cache)
+        # persistent compilation cache under the executor cache (when a
+        # directory is configured or JAX_COMPILATION_CACHE_DIR is set):
+        # every AOT build below lands on disk, and a fresh process pointed
+        # at the same directory deserializes instead of compiling
+        EXECUTOR_CACHE.enable_persistent(self.config.compile_cache)
         if self.config.mesh:
             from repro.dist.bankmesh import MeshBankPool
             self.pool = MeshBankPool(self.config.banks, self.config.bank_width,

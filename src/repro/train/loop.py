@@ -120,7 +120,7 @@ def make_dp_train_step(cfg: ModelCfg, mesh, *, axis_name: str = "data",
     ``step(state, batch)`` ready to ``jax.jit``; build the matching state
     with :func:`init_dp_state`.
     """
-    from repro.dist._jaxcompat import shard_map
+    from jax import shard_map
     from repro.dist.compress import ef_topk_psum_tree
 
     n_dev = mesh.shape[axis_name]

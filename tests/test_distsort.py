@@ -16,17 +16,7 @@ def test_sharded_topk_matches_monolithic():
         import sys; sys.path.insert(0, "src")
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        try:
-            shard_map = jax.shard_map            # jax >= 0.5
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
-        def smap(fn, **kw):
-            # older shard_map mis-tracks replication of the psum-in-scan
-            # carry; the documented workaround is disabling the rep check
-            try:
-                return shard_map(fn, check_rep=False, **kw)
-            except TypeError:                    # kwarg renamed on newer jax
-                return shard_map(fn, **kw)
+        smap = jax.shard_map
         from repro.core.distsort import topk_mask_sharded, global_min_sharded
         from repro.core.topk import topk_mask, to_sortable_uint
 
@@ -66,7 +56,7 @@ def test_collectives_property_match_numpy_oracle():
         import sys; sys.path.insert(0, "src")
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.dist._jaxcompat import shard_map
+        from jax import shard_map
         from repro.core.distsort import (
             global_min_sharded, kth_largest_sharded, topk_mask_sharded)
 

@@ -120,3 +120,46 @@ def test_property_bitonic_sorts(seed, logn):
     got = np.asarray(bitonic_sort(jnp.asarray(x), use_pallas=True,
                                   interpret=True))
     assert np.array_equal(got, np.sort(x, axis=-1))
+
+
+@pytest.mark.parametrize("backend,use_pallas,interpret,want", [
+    ("tpu", None, None, (True, False)),     # compiled on the chip
+    ("tpu", None, True, (True, True)),      # explicit interpret is honoured
+    ("tpu", False, None, (False, False)),
+    ("cpu", None, None, (False, True)),     # XLA reference off the chip
+    ("cpu", True, None, (True, True)),      # Pallas off the chip interprets
+    ("cpu", None, True, (True, True)),
+])
+def test_dispatch_resolves_from_platform(monkeypatch, backend, use_pallas,
+                                         interpret, want):
+    from repro.kernels import dispatch
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: backend)
+    assert dispatch.resolve(use_pallas, interpret) == want
+    if interpret is None:          # None never becomes interpret on a TPU
+        assert dispatch.resolve_interpret(None) == (backend != "tpu")
+
+
+def test_colskip_dense_carrier_has_no_compiled_kernel(monkeypatch):
+    """On TPU the dense baseline runs on the XLA reference (impl "xla");
+    the packed machine runs the compiled kernel (impl "pallas")."""
+    from repro.kernels import dispatch
+    from repro.kernels.colskip.ops import resolve_colskip
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    assert resolve_colskip(packed=True) == (True, False, "pallas")
+    assert resolve_colskip(packed=False) == (False, False, "xla")
+    assert resolve_colskip(packed=False, interpret=True) == (
+        True, True, "interpret")
+
+
+@pytest.mark.parametrize("use_pallas,interpret,impl", [
+    (None, None, "xla"), (True, True, "interpret")])
+def test_colskip_tile_reports_impl(use_pallas, interpret, impl):
+    from repro.sortserve import SortRequest
+    from repro.sortserve.backends import ColskipBackend
+    from repro.sortserve.batcher import Batcher
+    b = Batcher(tile_rows=2, min_bucket=8)
+    b.add(SortRequest("sort", np.arange(40, 0, -1, dtype=np.uint32)))
+    tile = b.flush()[0]
+    res = ColskipBackend(use_pallas=use_pallas, interpret=interpret).run(tile)
+    assert res.meta["impl"] == impl
+    assert np.array_equal(res.values[0, :40], np.arange(1, 41))

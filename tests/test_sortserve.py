@@ -698,3 +698,35 @@ def test_property_served_stream_equals_oracle(seed, n_req):
     reqs = make_workload(n_req, min_len=4, max_len=48, seed=seed)
     for req, resp in zip(reqs, engine.submit(reqs)):
         assert check_against_oracle(req, resp)
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_directory_follows_the_environment(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache directory and the
+    engine sets no other; without it, the configured directory is used."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    want = str(tmp_path / ("env" if env_dir else "cfg"))
+    code = textwrap.dedent(f"""
+        import jax
+        from repro.sortserve import EngineConfig, SortServeEngine
+        from repro.sortserve.backends import EXECUTOR_CACHE
+        SortServeEngine(EngineConfig(compile_cache={str(tmp_path / "cfg")!r}))
+        assert jax.config.jax_compilation_cache_dir == {want!r}, \\
+            jax.config.jax_compilation_cache_dir
+        assert EXECUTOR_CACHE.persistent_dir == {want!r}
+        print("OK")
+    """)
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")]
+        + [p for p in [os.environ.get("PYTHONPATH")] if p])
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "OK" in out.stdout
