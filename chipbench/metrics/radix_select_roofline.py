@@ -1,0 +1,10 @@
+"""The radix selection of the ``radix_topk`` backend
+(``sortserve/backends._radix_select``): the bytes of its
+tiles at HBM bandwidth over the device's busy time
+(``chipbench/lib/roofline.py``)."""
+
+from chipbench.lib.roofline import roofline_share
+
+
+def read(ctx):
+    return roofline_share(ctx, "radix_topk")
