@@ -335,6 +335,8 @@ def capture(engine, source: str | None = None,
     c[PREFIX + "verify_failures_total"] = agg["verify_failures"]
     c[PREFIX + "result_cache_hits_total"] = agg["cache_hits"]
     c[PREFIX + "result_cache_misses_total"] = agg["cache_misses"]
+    c[PREFIX + "queue_wait_seconds_total"] = agg["queue_wait_s"]
+    c[PREFIX + "queue_waits_total"] = agg["queue_waits"]
     for key in ("hits", "misses", "prewarmed"):
         c[f"{PREFIX}executor_cache_{key}_total"] = engine._exec_stats[key]
     # process-global split (same scope as the live executor_cache section);

@@ -38,17 +38,25 @@ Design constraints, in order:
 domain (one track per request), process 2 is the virtual-time domain at the
 modeled clock (one track per bank, plus a scheduler-event track), so both
 domains sit in one viewer, zoomable together.
+
+:func:`span` is the recorder's bridge to the profiler's clock: the serving
+path opens one ``jax.profiler.TraceAnnotation`` per layer boundary (feed,
+bucket, schedule, execute, the backend call and its put / launch / wait /
+fetch, compile, scatter), so a ``jax.profiler`` capture puts host work and device idle
+time on one timeline.  The profiler session is the only switch; with none
+active a span costs about a microsecond and records nothing.
 """
 
 from __future__ import annotations
 
-import itertools
 import json
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.costmodel import BASE_CLOCK_MHZ
 
-__all__ = ["Tracer"]
+__all__ = ["Tracer", "span"]
 
 # statuses a finalized request chain can carry
 SERVED, CACHE_HIT, SHED, FAILED, ABORTED = (
@@ -75,6 +83,16 @@ _RECORD_TEMPLATE = {
 }
 
 
+def span(name: str, **meta) -> TraceAnnotation:
+    """A profiler span around one layer of the serving path.
+
+    ``name`` is ``sortserve.<phase>``; ``meta`` (tile id, backend, shape)
+    rides on the event as its stats.  Spans go per tile or per call, never
+    per request, and they nest: a span's self time is its duration minus
+    its children's.  Recorded only while a ``jax.profiler`` trace runs."""
+    return TraceAnnotation(name, **meta)
+
+
 class Tracer:
     """Ring-buffered span recorder; inject via ``EngineConfig(tracer=...)``.
 
@@ -98,7 +116,6 @@ class Tracer:
         self._events: deque = deque(maxlen=capacity)
         self._active: dict[int, dict] = {}            # rid -> open chain
         self._open_tiles: dict[int, dict] = {}        # seq -> open record
-        self._seq = itertools.count(1)
         # Chain/record dicts are preallocated here and recycled through
         # freelists when the rings wrap, so recording allocates (almost)
         # nothing on the serving path: the pool promotes to the old GC
@@ -197,8 +214,10 @@ class Tracer:
     # ---------------------------------------------------------------- tiles
     def tile_dispatched(self, tile, wall: float) -> dict:
         """Open a tile record and tag the tile so scheduler events and the
-        execute hook find it back (``tile.obs["trace_seq"]``)."""
-        seq = next(self._seq)
+        execute hook find it back (``tile.obs["trace_seq"]``).  The record
+        takes the engine's tile id (``tile.obs["seq"]``), the one the
+        profiler spans carry, so both traces name a tile alike."""
+        seq = tile.obs["seq"]
         tile.obs["trace_seq"] = seq
         free = self._record_free
         if free:

@@ -39,7 +39,11 @@ hardware-cycle telemetry either way).
 Execution itself runs through a process-level :class:`ExecutorCache` of
 AOT-compiled tile executors keyed by ``(backend, B, N, k, flags)`` with
 donated input buffers — a tile whose signature was seen before skips
-tracing/lowering entirely and goes straight to the warm executable.
+tracing/lowering entirely and goes straight to the warm executable.  Each
+executor carries its backend's name (HLO module ``jit_<name>``, ops under
+the ``<name>`` scope), and every device backend runs a tile through one
+round trip, :func:`_round_trip` — put, launch, wait, fetch — each step a
+profiler span (:func:`repro.obs.tracer.span`).
 """
 
 from __future__ import annotations
@@ -53,6 +57,7 @@ import numpy as np
 
 from repro.core import costmodel
 from repro.core.costmodel import estimate_colskip_cycles
+from repro.obs.tracer import span
 
 from .batcher import Tile
 
@@ -163,7 +168,8 @@ class ExecutorCache:
             # builder failed: loop and take over the build
         fn = None
         try:
-            fn = build()                      # compile outside the lock
+            with span("sortserve.compile", key=repr(key)):
+                fn = build()                  # compile outside the lock
         finally:
             with self._lock:
                 if fn is not None:
@@ -193,31 +199,62 @@ def checkout_cache_dir() -> str:
     return str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
-def _aot_compile(fn, *shapes, donate_first: bool = True):
-    """``jax.jit(fn).lower(*shapes).compile()`` with the first buffer donated.
+def _aot_compile(name: str, body, b: int, n: int, *,
+                 donate_first: bool = True):
+    """The executor ``name`` for a ``(b, n)`` uint32 tile:
+    ``jax.jit(body).lower(...).compile()`` with the tile donated.
 
-    Donation is skipped on CPU, where XLA cannot reuse the buffers and would
-    warn on every executable instead."""
+    The jitted function is called ``name`` and its body runs under the
+    ``name`` scope, so the HLO module is ``jit_<name>`` and a device trace
+    can tell the executors apart.  Donation is skipped on CPU, where XLA
+    cannot reuse the buffers and would warn on every executable instead."""
     import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        with jax.named_scope(name):
+            return body(x)
+
+    fn.__name__ = fn.__qualname__ = name
     donate = (0,) if donate_first and jax.default_backend() != "cpu" else ()
-    return jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
+    return jax.jit(fn, donate_argnums=donate).lower(
+        jax.ShapeDtypeStruct((b, n), jnp.uint32)).compile()
+
+
+def _round_trip(fn, tile: Tile, staged=None) -> tuple:
+    """Run one tile through its executor: the outputs as numpy arrays.
+
+    Four steps, each a profiler span carrying the tile id: ``put`` copies
+    the tile to the device (skipped when ``staged`` already holds it),
+    ``launch`` enqueues the executor, ``wait`` blocks until the device is
+    done, and ``fetch`` copies every output back to the host."""
+    import jax
+    import jax.numpy as jnp
+
+    seq = tile.obs.get("seq")
+    with span("sortserve.execute.put", tile=seq):
+        arr = staged if staged is not None else jnp.asarray(tile.data,
+                                                            jnp.uint32)
+    with span("sortserve.execute.launch", tile=seq):
+        out = fn(arr)
+    outs = out if isinstance(out, tuple) else (out,)
+    with span("sortserve.execute.wait", tile=seq):
+        jax.block_until_ready(outs)
+    with span("sortserve.execute.fetch", tile=seq, arrays=len(outs)):
+        return tuple(np.asarray(o) for o in outs)
 
 
 def _compiled_colskip(b: int, n: int, w: int, state_k: int,
                       stop: int | None, use_pallas: bool,
                       interpret: bool, packed: bool):
     """Warm executor for one colskip tile signature."""
-    import jax
-    import jax.numpy as jnp
-
     from repro.kernels.colskip import colskip_sort_batched
 
     key = ("colskip", b, n, w, state_k, stop, use_pallas, interpret, packed)
     return EXECUTOR_CACHE.get(key, lambda: _aot_compile(    # -> (fn, warm)
-        lambda x: colskip_sort_batched(
+        "colskip", lambda x: colskip_sort_batched(
             x, w, state_k, use_pallas=use_pallas, interpret=interpret,
-            stop_after=stop, packed=packed),
-        jax.ShapeDtypeStruct((b, n), jnp.uint32)))
+            stop_after=stop, packed=packed), b, n))
 
 
 @dataclass
@@ -349,16 +386,13 @@ class ColskipBackend(Backend):
         self.packed = packed
 
     def run(self, tile: Tile) -> TileResult:
-        import jax.numpy as jnp
         stop = tile.k if tile.op == "kmin" else None
         b, n = tile.data.shape
         fn, warm = _compiled_colskip(b, n, self.w, self.state_k, stop,
                                      self.use_pallas, self.interpret,
                                      self.packed)
-        vals, order, crs, cycles = fn(jnp.asarray(tile.data, jnp.uint32))
-        vals = np.asarray(vals)
-        order = np.asarray(order, dtype=np.int32)
-        return TileResult(vals, order,
+        vals, order, crs, cycles = _round_trip(fn, tile)
+        return TileResult(vals, np.asarray(order, np.int32),
                           np.asarray(crs, np.int64), np.asarray(cycles, np.int64),
                           self.name, meta={"w": self.w, "state_k": self.state_k,
                                            "stop_after": stop,
@@ -426,9 +460,6 @@ class ShardedColskipBackend(Backend):
                 self.packed, self.fuse, self._axes(), self._fingerprint)
 
     def _mesh_executor(self, b: int, n: int, stop_eff: int):
-        import jax
-        import jax.numpy as jnp
-
         from repro.dist.bankmesh import sharded_tile_fn
         # AOT-compiled through the executor cache (like the local
         # backends), so a cold mesh tile is visible as a cache miss —
@@ -437,9 +468,10 @@ class ShardedColskipBackend(Backend):
         # (the TPU compiler refuses the alias)
         return EXECUTOR_CACHE.get(self._mesh_key(b, n, stop_eff),
                                   lambda: _aot_compile(
+            "colskip_mesh",
             sharded_tile_fn(self.mesh, self.axis_name, self.w,
                             self.state_k, stop_eff, self.packed, self.fuse),
-            jax.ShapeDtypeStruct((b, n), jnp.uint32), donate_first=False))
+            b, n, donate_first=False))
 
     def prefetch(self, tile: Tile) -> bool:
         """Stage the next tile's device transfer (double buffering).
@@ -462,8 +494,6 @@ class ShardedColskipBackend(Backend):
         return True
 
     def run(self, tile: Tile) -> TileResult:
-        import jax.numpy as jnp
-
         from repro.dist.bankmesh import collective_rounds
         b, n = tile.data.shape
         n_dev = self.n_devices
@@ -475,9 +505,8 @@ class ShardedColskipBackend(Backend):
         if n % n_dev == 0 and n_dev > 1:
             stop_eff = min(stop, n) if stop is not None else n
             fn, warm = self._mesh_executor(b, n, stop_eff)
-            arr = staged[1] if prefetch_hit else jnp.asarray(tile.data,
-                                                             jnp.uint32)
-            vals, order, crs, cycles = fn(arr)
+            vals, order, crs, cycles = _round_trip(
+                fn, tile, staged[1] if prefetch_hit else None)
             banks_used = n_dev
             rounds = collective_rounds(self.w, stop_eff, self.fuse)
             coll = {"coll_rounds": rounds["rounds"],
@@ -486,9 +515,9 @@ class ShardedColskipBackend(Backend):
         else:
             fn, warm = _compiled_colskip(b, n, self.w, self.state_k, stop,
                                          False, None, self.packed)
-            vals, order, crs, cycles = fn(jnp.asarray(tile.data, jnp.uint32))
+            vals, order, crs, cycles = _round_trip(fn, tile)
             banks_used = 1
-        return TileResult(np.asarray(vals), np.asarray(order, np.int32),
+        return TileResult(vals, np.asarray(order, np.int32),
                           np.asarray(crs, np.int64),
                           np.asarray(cycles, np.int64), self.name,
                           meta={"w": self.w, "state_k": self.state_k,
@@ -523,34 +552,26 @@ class RadixTopkBackend(Backend):
     name = "radix_topk"
     ops = frozenset(("topk", "kmin"))
 
-    def run(self, tile: Tile) -> TileResult:
-        import jax
-        import jax.numpy as jnp
+    @staticmethod
+    def _executor(b: int, n: int, k: int, kmin: bool):
+        return EXECUTOR_CACHE.get(("radix_topk", b, n, k, kmin),
+                                  lambda: _aot_compile(
+            "radix_topk", lambda x: _radix_select(x, k, kmin), b, n))
 
+    def run(self, tile: Tile) -> TileResult:
         b, n = tile.data.shape
-        kmin = tile.op == "kmin"
-        key = ("radix_topk", b, n, tile.k, kmin)
-        fn, warm = EXECUTOR_CACHE.get(key, lambda: _aot_compile(
-            lambda x: _radix_select(x, tile.k, kmin),
-            jax.ShapeDtypeStruct((b, n), jnp.uint32)))
-        vals, idxs, reads = fn(jnp.asarray(tile.data, jnp.uint32))
+        fn, warm = self._executor(b, n, tile.k, tile.op == "kmin")
+        vals, idxs, reads = _round_trip(fn, tile)
         reads = np.asarray(reads, np.int64)
-        return TileResult(np.asarray(vals), np.asarray(idxs, np.int32),
+        return TileResult(vals, np.asarray(idxs, np.int32),
                           reads, None, self.name,
                           meta={"planes_max": int(reads.max(initial=0)),
                                 "exec_warm": warm})
 
     def warm(self, b: int, n: int, op: str, k: int | None) -> bool:
-        import jax
-        import jax.numpy as jnp
-
         if k is None:
             return False                    # selection ops always carry k
-        kmin = op == "kmin"
-        key = ("radix_topk", b, n, k, kmin)
-        _, hit = EXECUTOR_CACHE.get(key, lambda: _aot_compile(
-            lambda x: _radix_select(x, k, kmin),
-            jax.ShapeDtypeStruct((b, n), jnp.uint32)))
+        _, hit = self._executor(b, n, k, op == "kmin")
         return not hit
 
 
@@ -561,17 +582,18 @@ class JaxSortBackend(Backend):
     name = "jaxsort"
     ops = frozenset(("sort", "argsort", "kmin"))
 
-    def run(self, tile: Tile) -> TileResult:
-        import jax
+    @staticmethod
+    def _executor(b: int, n: int):
         import jax.numpy as jnp
 
+        return EXECUTOR_CACHE.get(("jaxsort", b, n), lambda: _aot_compile(
+            "jaxsort", lambda x: jnp.argsort(x, axis=-1, stable=True), b, n))
+
+    def run(self, tile: Tile) -> TileResult:
         b, n = tile.data.shape
-        key = ("jaxsort", b, n)
-        fn, warm = EXECUTOR_CACHE.get(key, lambda: _aot_compile(
-            lambda x: jnp.argsort(x, axis=-1, stable=True),
-            jax.ShapeDtypeStruct((b, n), jnp.uint32)))
-        order = np.asarray(fn(jnp.asarray(tile.data, jnp.uint32)),
-                           dtype=np.int32)
+        fn, warm = self._executor(b, n)
+        (order,) = _round_trip(fn, tile)
+        order = np.asarray(order, np.int32)
         vals = np.take_along_axis(tile.data, order, axis=-1)
         if tile.op == "kmin":
             vals, order = vals[:, :tile.k], order[:, :tile.k]
@@ -580,13 +602,7 @@ class JaxSortBackend(Backend):
                           estimated_cycles=est, meta={"exec_warm": warm})
 
     def warm(self, b: int, n: int, op: str, k: int | None) -> bool:
-        import jax
-        import jax.numpy as jnp
-
-        key = ("jaxsort", b, n)
-        EXECUTOR_CACHE.get(key, lambda: _aot_compile(
-            lambda x: jnp.argsort(x, axis=-1, stable=True),
-            jax.ShapeDtypeStruct((b, n), jnp.uint32)))
+        self._executor(b, n)
         return True
 
 

@@ -62,6 +62,7 @@ import threading
 import time
 import dataclasses
 import hashlib
+import itertools
 from collections import OrderedDict, deque
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
@@ -90,6 +91,7 @@ from repro.obs.calibration import CalibrationTable
 from repro.obs.export import render_openmetrics
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.slo import SLOTracker
+from repro.obs.tracer import span
 
 __all__ = ["AsyncSortServe", "BackoffPolicy", "EngineConfig", "RetryAfter",
            "SortServeEngine", "SortSession"]
@@ -257,6 +259,9 @@ class SortServeEngine:
         # process-global; per-call warm flags keep attribution correct even
         # with several engines or threads sharing it)
         self._exec_stats = {"hits": 0, "misses": 0, "prewarmed": 0}
+        # every dispatched tile's id (``tile.obs["seq"]``): the profiler
+        # spans and the flight recorder name a tile by it alike
+        self._tile_ids = itertools.count(1)
         # traffic-class -> set of tile signatures seen from that class's
         # sessions; begin(traffic_class=...) prewarms executors from it
         self._class_menus: dict[str, set] = {}
@@ -270,6 +275,9 @@ class SortServeEngine:
             "requests": 0, "column_reads": 0, "cycles_exact": 0,
             "cycles_estimated": 0.0, "verify_failures": 0,
             "cache_hits": 0, "cache_misses": 0,
+            # wall seconds from each request's feed to the launch of the
+            # tile that served it, summed, and the requests counted
+            "queue_wait_s": 0.0, "queue_waits": 0,
             "per_backend": {}, "per_op": {}, "modeled_hw": {},
             # mesh collective-round accounting (§IV manager rounds; the
             # mesh-side CR analogue): fixed shape, zeros off the mesh path.
@@ -484,8 +492,17 @@ class SortServeEngine:
         if pf is not None and pf(tile):
             self._agg["collectives"]["prefetch_staged"] += 1
 
-    def _execute(self, tile: Tile,
-                 traffic_class: str | None = None) -> TileResult:
+    def _execute(self, tile: Tile, traffic_class: str | None = None,
+                 t_fed: dict | None = None) -> TileResult:
+        """Run one tile on the backend the policy picks, and account for
+        it.  ``t_fed`` maps the tile's request ids to their feed instants
+        (the session's), for the queue wait up to this launch."""
+        with span("sortserve.execute", tile=tile.obs.get("seq"),
+                  rows=tile.shape[0], n=tile.shape[1]) as sp:
+            return self._run_tile(sp, tile, traffic_class, t_fed)
+
+    def _run_tile(self, sp, tile: Tile, traffic_class: str | None,
+                  t_fed: dict | None) -> TileResult:
         backend = self.policy.choose(tile, traffic_class=traffic_class)
         inj = self._injector
         faulty = (inj is not None and inj.active
@@ -499,8 +516,10 @@ class SortServeEngine:
             if fb is not None:
                 backend, faulty = fb, False
                 self._fault_agg["fallbacks"] += 1
+        sp.set_metadata(backend=backend.name)
         t0 = self._clock()
-        result = backend.run(tile)
+        with span("sortserve.execute.run", tile=tile.obs.get("seq")):
+            result = backend.run(tile)
         t1 = self._clock()
         if faulty:
             # injection + verification guard, in virtual time, before any
@@ -518,6 +537,10 @@ class SortServeEngine:
                     tile.obs.get("bank_ids", ()))
                 raise
         result.meta["wall_s"] = t1 - t0
+        if t_fed is not None:
+            for req, _ in tile.entries:
+                self._agg["queue_wait_s"] += t0 - t_fed[req.request_id]
+            self._agg["queue_waits"] += len(tile.entries)
         warm = result.meta.get("exec_warm")     # None: backend has no cache
         if warm is not None:
             self._exec_stats["hits" if warm else "misses"] += 1
@@ -681,6 +704,11 @@ class SortServeEngine:
                 "p95": float(np.percentile(lat, 95)),
                 "max": float(lat.max()),
             },
+            # feed -> launch of the serving tile, wall seconds on the
+            # engine clock (the scheduler's queue_wait_vt is modelled
+            # cycles): the sum and the requests it covers
+            "queue_wait_s": {"sum": self._agg["queue_wait_s"],
+                             "count": self._agg["queue_waits"]},
             "column_reads": self._agg["column_reads"],
             "cycles_exact": self._agg["cycles_exact"],
             "cycles_estimated": self._agg["cycles_estimated"],
@@ -913,7 +941,7 @@ class SortSession:
         its own tile (the front door's failure-isolation retry — other
         callers' open buckets are untouched)."""
         e = self.engine
-        with e._lock:
+        with e._lock, span("sortserve.feed"):
             now = e._clock() if now is None else now
             e._validate_batch(requests, prior_ids=self._outstanding)
             use_cache = e.config.cache_size > 0
@@ -949,14 +977,15 @@ class SortSession:
                     solo.append(req)
                 else:
                     self._batcher.add(req, now)
-            tiles = []
-            for req in solo:                  # one private tile per request
-                lone = Batcher(e.config.tile_rows, e.config.min_bucket,
-                               stats=e.batcher.stats)
-                lone.add(req, now)
-                tiles += lone.flush()
-            tiles += (self._batcher.flush() if flush
-                      else self._batcher.take_ready(now, self.max_age_s))
+            with span("sortserve.bucket"):
+                tiles = []
+                for req in solo:              # one private tile per request
+                    lone = Batcher(e.config.tile_rows, e.config.min_bucket,
+                                   stats=e.batcher.stats)
+                    lone.add(req, now)
+                    tiles += lone.flush()
+                tiles += (self._batcher.flush() if flush
+                          else self._batcher.take_ready(now, self.max_age_s))
             self._dispatch(tiles)
             return self._take()
 
@@ -965,14 +994,18 @@ class SortSession:
         e = self.engine
         with e._lock:
             now = e._clock() if now is None else now
-            self._dispatch(self._batcher.take_ready(now, self.max_age_s))
+            with span("sortserve.bucket"):
+                tiles = self._batcher.take_ready(now, self.max_age_s)
+            self._dispatch(tiles)
             return self._take()
 
     def drain(self) -> list[SortResponse]:
         """Close every open bucket and return all remaining responses."""
         e = self.engine
         with e._lock:
-            self._dispatch(self._batcher.flush())
+            with span("sortserve.bucket"):
+                tiles = self._batcher.flush()
+            self._dispatch(tiles)
             if self.strict and self._outstanding:
                 raise RuntimeError(
                     f"{len(self._outstanding)} requests vanished without "
@@ -1000,6 +1033,8 @@ class SortSession:
         e = self.engine
         if tiles:
             self._stats["tiles"] += len(tiles)
+            for tile in tiles:
+                tile.obs["seq"] = next(e._tile_ids)
             tracer = e._tracer
             if tracer is not None:
                 now = e._clock()
@@ -1007,12 +1042,14 @@ class SortSession:
                     rec = tracer.tile_dispatched(tile, now)
                     for req, _ in tile.entries:
                         tracer.request_dispatched(req.request_id, rec, now)
-            e.scheduler.feed(
-                tiles,
-                lambda tile: e._execute(tile,
-                                        traffic_class=self.traffic_class),
-                sink=self._on_tile, strict=self.strict, owner=self)
-            e.scheduler.pump()
+            with span("sortserve.schedule", tiles=len(tiles)):
+                e.scheduler.feed(
+                    tiles,
+                    lambda tile: e._execute(
+                        tile, traffic_class=self.traffic_class,
+                        t_fed=self._t_fed),
+                    sink=self._on_tile, strict=self.strict, owner=self)
+                e.scheduler.pump()
 
     def _on_tile(self, tile: Tile, result, exc) -> None:
         e = self.engine
@@ -1036,6 +1073,13 @@ class SortSession:
                     e._tracer.request_failed(req.request_id, now,
                                              "shed" if shed else "failed")
             return
+        with span("sortserve.scatter", tile=tile.obs.get("seq")):
+            self._retire(tile, result)
+
+    def _retire(self, tile: Tile, result) -> None:
+        """Scatter a served tile's rows into responses, commit them to the
+        result cache, and prune the requests' stamps."""
+        e = self.engine
         now = e._clock()
         use_cache = e.config.cache_size > 0
         tracer = e._tracer

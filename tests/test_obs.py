@@ -16,10 +16,19 @@ The acceptance surface:
     perturb the observed);
   * **windowed metrics / calibration primitives** — sliding-window counts,
     exact recent quantiles, snapshot/restore (the engine rollback path),
-    and the measured-vs-modeled ratio table.
+    and the measured-vs-modeled ratio table;
+  * **profiler spans** — under ``jax.profiler.trace`` every device tile
+    carries ``sortserve.execute`` ⊃ ``execute.run`` ⊃ put / launch / wait /
+    fetch under one tile id, beside feed / bucket / schedule / scatter; with no profiler
+    the golden workload's answers and telemetry are unchanged and nothing
+    is written; every executor is named in its HLO; the wall-clock queue
+    wait (feed -> launch) is exact under a fake clock.
 """
 
+import contextlib
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -199,18 +208,15 @@ def test_tracing_off_is_default_and_spanless():
         eng.dump_trace("/dev/null")
 
 
-def test_traced_golden_workload_is_byte_identical():
-    """Observation must not perturb the observed: the golden workload run
-    with the recorder ON reproduces the recorded telemetry byte-for-byte,
-    and the untraced default is pinned separately by test_continuous."""
-    reqs = make_workload(40, min_len=8, max_len=128, seed=21)
-    tracer = Tracer()
-    eng = make_engine(tracer=tracer)
-    got = eng.submit(reqs)
-    # rebuild the golden payload shape from the traced run
+def _golden_workload():
+    return make_workload(40, min_len=8, max_len=128, seed=21)
+
+
+def _golden_payload(eng, got) -> dict:
+    """The recorded golden file's shape, rebuilt from a live run."""
     from test_continuous import _bank_totals, _digest
     telem = eng.telemetry()
-    payload = {
+    return {
         "responses": [
             {"backend": r.backend, "cycles": r.cycles,
              "column_reads": r.column_reads,
@@ -225,8 +231,167 @@ def test_traced_golden_workload_is_byte_identical():
             "bank_totals": list(_bank_totals(eng)),
         },
     }
-    assert payload == json.loads(GOLDEN.read_text())
+
+
+def test_traced_golden_workload_is_byte_identical():
+    """Observation must not perturb the observed: the golden workload run
+    with the recorder ON reproduces the recorded telemetry byte-for-byte,
+    and the untraced default is pinned separately by test_continuous."""
+    reqs = _golden_workload()
+    tracer = Tracer()
+    eng = make_engine(tracer=tracer)
+    got = eng.submit(reqs)
+    assert _golden_payload(eng, got) == json.loads(GOLDEN.read_text())
     assert tracer.span_count() == len(reqs)
+
+
+@pytest.mark.parametrize("profiler", [False, True])
+def test_program_spans_leave_the_golden_workload_byte_identical(
+        profiler, tmp_path, monkeypatch):
+    """The profiler spans on the serving path change nothing they observe:
+    with no profiler (the default) and under a live ``jax.profiler``
+    capture alike, the golden workload answers as recorded and the whole
+    telemetry equals a plain run's; with no profiler nothing is written."""
+    import jax
+
+    make_engine(clock=FakeClock()).submit(_golden_workload())   # warm up
+    ref = make_engine(clock=FakeClock())
+    ref.submit(_golden_workload())
+    monkeypatch.chdir(tmp_path)
+    eng = make_engine(clock=FakeClock())
+    with (jax.profiler.trace(str(tmp_path / "profile")) if profiler
+          else contextlib.nullcontext()):
+        got = eng.submit(_golden_workload())
+    assert _golden_payload(eng, got) == json.loads(GOLDEN.read_text())
+    assert eng.telemetry() == ref.telemetry()
+    assert sorted(os.listdir(tmp_path)) == (["profile"] if profiler else [])
+
+
+# ------------------------------------------------------- profiler spans
+DEVICE_BACKENDS = ("colskip", "radix_topk", "jaxsort")
+ROUND_TRIP = tuple(f"sortserve.execute.{step}"
+                   for step in ("put", "launch", "wait", "fetch"))
+
+
+def _profiled_spans(tmp_path, run) -> list[tuple[str, int, int, dict]]:
+    """``run()`` under a ``jax.profiler`` capture: every ``sortserve.*``
+    host event as (name, start_ns, end_ns, stats)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        run()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                           dict(e.stats)) for e in line.events
+                          if e.name.startswith("sortserve.")]
+    return spans
+
+
+def test_profiler_spans_cover_every_tile_of_the_serving_path(tmp_path):
+    eng = make_engine(FakeClock())
+    session = eng.begin()
+    # colskip up to the 128-wide simulation cap, jaxsort past it, radix
+    # selection for top-k
+    reqs = (reqs_of([16, 30, 9, 64, 40]) + reqs_of([200, 256], seed=1)
+            + [SortRequest(op="topk", payload=r.payload, k=4)
+               for r in reqs_of([50, 100, 128], seed=2)])
+    spans = _profiled_spans(tmp_path, lambda: (
+        session.feed(reqs[:6]), session.feed(reqs[6:], flush=True),
+        session.drain()))
+    names = {name for name, *_ in spans}
+    assert {"sortserve.feed", "sortserve.bucket", "sortserve.schedule",
+            "sortserve.scatter"} <= names
+    executes = [sp for sp in spans if sp[0] == "sortserve.execute"]
+    assert {sp[3]["backend"] for sp in executes} == set(DEVICE_BACKENDS)
+    assert len({sp[3]["tile"] for sp in executes}) == len(executes) \
+        == eng.telemetry()["batcher"]["tiles"]
+    scattered = {sp[3]["tile"] for sp in spans
+                 if sp[0] == "sortserve.scatter"}
+    for _, start, end, stats in executes:
+        (run,) = [sp for sp in spans if sp[0] == "sortserve.execute.run"
+                  and sp[3]["tile"] == stats["tile"]]
+        assert start <= run[1] <= run[2] <= end
+        steps = [sp for sp in spans if sp[0] in ROUND_TRIP
+                 and sp[3]["tile"] == stats["tile"]]
+        # one round trip per tile, in order, inside its backend call
+        assert [sp[0] for sp in sorted(steps, key=lambda sp: sp[1])] \
+            == list(ROUND_TRIP)
+        assert all(run[1] <= s <= e <= run[2] for _, s, e, _ in steps)
+        fetch = next(sp for sp in steps if sp[0].endswith("fetch"))
+        assert fetch[3]["arrays"] == {"colskip": 4, "radix_topk": 3,
+                                      "jaxsort": 1}[stats["backend"]]
+        assert stats["rows"] == 4 and stats["tile"] in scattered
+
+
+@pytest.mark.parametrize("backend", DEVICE_BACKENDS + ("colskip_mesh",))
+def test_every_executor_carries_its_backend_name(backend):
+    from repro.sortserve.backends import resolve_backends
+
+    (be,) = resolve_backends([backend])
+    if backend == "colskip_mesh":
+        fn, _ = be._mesh_executor(4, 16, 16)
+    elif backend == "colskip":
+        from repro.sortserve.backends import _compiled_colskip
+        fn, _ = _compiled_colskip(4, 16, be.w, be.state_k, None,
+                                  be.use_pallas, be.interpret, be.packed)
+    else:
+        fn, _ = be._executor(4, 16, 3, False) if backend == "radix_topk" \
+            else be._executor(4, 16)
+    hlo = fn.as_text()
+    assert hlo.startswith(f"HloModule jit_{backend},")
+    assert f'op_name="jit({backend})/{backend}/' in hlo
+
+
+# -------------------------------------------------- wall-clock queue wait
+def _ticking_engine(clock):
+    """One backend whose every run takes one second of the fake clock."""
+    eng = make_engine(clock, backends=("numpy",))
+    be = eng.policy.by_name["numpy"]
+    run = be.run
+
+    def slow(tile):
+        clock.tick(1.0)
+        return run(tile)
+    be.run = slow
+    return eng
+
+
+def _flushed_tiles(eng, clock):
+    # twelve requests at t=0, three 4-row tiles launched at t=0, 1, 2
+    eng.begin().feed(reqs_of([16] * 12), flush=True)
+    return 4 * (0.0 + 1.0 + 2.0), 12
+
+
+def _aged_bucket(eng, clock):
+    # a bucket of three closes on age at t=0.75: fed at 0, 0 and 0.25
+    session = eng.begin(max_age_s=0.5)
+    session.feed(reqs_of([16, 16]))
+    clock.tick(0.25)
+    session.feed(reqs_of([16], seed=1))
+    clock.tick(0.5)
+    session.poll()
+    return 0.75 + 0.75 + 0.5, 3
+
+
+@pytest.mark.parametrize("load", [_flushed_tiles, _aged_bucket])
+def test_queue_wait_is_exact_wall_time_under_a_fake_clock(load):
+    clock = FakeClock()
+    eng = _ticking_engine(clock)
+    want_sum, want_count = load(eng, clock)
+    telem = eng.telemetry()
+    assert telem["queue_wait_s"] == {"sum": want_sum, "count": want_count}
+    assert telem["requests"] == want_count
+    counters = eng.telemetry_snapshot().counters
+    assert counters["sortserve_queue_wait_seconds_total"] == want_sum
+    assert counters["sortserve_queue_waits_total"] == want_count
 
 
 # ------------------------------------------------------- chrome trace JSON
