@@ -93,6 +93,7 @@ def colskip_sort(values: np.ndarray, w: int = 32, k: int = 2,
     crs = 0
     drains = 0
     iterations = 0
+    starts: list[int] = []        # each iteration's first plane (-1: none)
     remaining = stop
 
     while remaining > 0:
@@ -106,6 +107,7 @@ def colskip_sort(values: np.ndarray, w: int = 32, k: int = 2,
             alive = ~sorted_mask
             start = s_top
             fresh = True
+        starts.append(start)
 
         seen_mixed = False
         for sig in range(start, -1, -1):
@@ -144,5 +146,6 @@ def colskip_sort(values: np.ndarray, w: int = 32, k: int = 2,
         column_reads=crs,
         drains=drains,
         iterations=iterations,
-        meta={"algo": "colskip", "w": w, "k": k, "stop_after": stop},
+        meta={"algo": "colskip", "w": w, "k": k, "stop_after": stop,
+              "starts": starts},
     )

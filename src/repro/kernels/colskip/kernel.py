@@ -18,9 +18,16 @@ the paper's exact control structure at tile granularity (and is the unit the
 multi-bank tests shard).
 
 NOTE on SIMD adaptation: rows traverse data-dependently different column
-ranges; the kernel vectorizes by predicating each row's activity, so a tile's
-wall-clock follows its slowest row while CR telemetry stays per-row exact —
-an explicitly recorded deviation from the per-array hardware latency.
+ranges; the kernel vectorizes by predicating each row's activity, so CR
+telemetry stays per-row exact while a tile walks, each iteration, the planes
+of its slowest unfinished row: from the highest start plane among the rows
+still draining down to plane 0, and only until every row has drained.  The
+planes above that start are inactive for every row, so skipping them is
+exact.  A tile's wall-clock thus follows its slowest row, not all w planes of
+every one of ``stop`` iterations — an explicitly recorded deviation from the
+per-array hardware latency.  The bounds hold where no cross-bank gate
+exists; the mesh realization keeps the fixed ``stop`` x ceil(w/fuse) walk
+(see :func:`_run_machine`).
 
 The default hot path is **lane-packed** (``packed=True``): the alive mask,
 the sorted mask, and the k-entry table masks are carried as
@@ -97,7 +104,7 @@ def colskip_machine(u, w: int, k: int, stop: int, *,
         carrier = _packed_carrier(lambda s: planes[s], n, planes.shape[-1])
     else:
         carrier = _dense_carrier(u)
-    sorted_m, pos, crs, drains = _run_machine(
+    sorted_m, pos, crs, drains, _ = _run_machine(
         carrier, tb, w, k, stop, _list_gate(or_any), drain_counts, fuse,
         vary)
     if packed:
@@ -107,9 +114,9 @@ def colskip_machine(u, w: int, k: int, stop: int, *,
 
 def _list_gate(or_any):
     """Adapt a stacked ``(TB, P)`` OR gate to the machine's list of
-    ``(TB, 1)`` predicates; no gate (one bank) stacks nothing at all."""
+    ``(TB, 1)`` predicates; no gate (one bank) stays None."""
     if or_any is None:
-        return lambda cols: cols
+        return None
 
     def gate(cols):
         out = or_any(jnp.concatenate(cols, axis=-1))
@@ -138,11 +145,17 @@ def _positions_from_words(pos3, n: int):
     return flat.reshape(lead + (flat.shape[-2] * LANE,))[..., :n]
 
 
+def _repeat(body, init):
+    """Run ``body(state) -> (state, more)`` until ``more`` is false."""
+    return jax.lax.while_loop(lambda c: c[1], lambda c: body(c[0]),
+                              (init, jnp.bool_(True)))[0]
+
+
 class _Carrier:
     """How one mask representation stores, reads and drains the masks."""
 
     def __init__(self, zeros, pos_zeros, col, unsorted, count, drain,
-                 any_row=_any_row, loop=jax.lax.fori_loop):
+                 any_row=_any_row, loop=jax.lax.fori_loop, repeat=_repeat):
         self.zeros = zeros          # tb -> empty mask
         self.pos_zeros = pos_zeros  # tb -> zero drain positions
         self.col = col              # sig -> bit-sig column mask
@@ -152,10 +165,12 @@ class _Carrier:
         self.drain = drain
         self.any_row = any_row      # mask -> (TB, 1) "saw a bit"
         self.loop = loop            # fori_loop(lo, hi, body, state)
+        self.repeat = repeat        # repeat(body, state): see _repeat
 
 
-def _vmem_loop(lo, hi, body, init):
-    """``fori_loop`` whose state lives in VMEM scratch, not in loop carries.
+def _in_vmem(loop, init):
+    """Run ``loop(get, put)`` with its state in VMEM scratch, not in loop
+    carries; returns the final state.
 
     Mosaic gives a carry the layout of its initial value, and a constant's
     layout is replicated, which the body's results cannot be relaid into;
@@ -165,22 +180,42 @@ def _vmem_loop(lo, hi, body, init):
     def scoped(*refs):
         for r, v in zip(refs, leaves):
             r[...] = v
+        get = lambda: tree.unflatten([r[...] for r in refs])
 
-        def step(i, _):
-            st = body(i, tree.unflatten([r[...] for r in refs]))
+        def put(st):
             for r, v in zip(refs, jax.tree.leaves(st)):
                 r[...] = v
             return 0
-        jax.lax.fori_loop(lo, hi, step, 0)
-        return tree.unflatten([r[...] for r in refs])
+        loop(get, put)
+        return get()
 
     return pl.run_scoped(scoped, *[pltpu.VMEM(v.shape, v.dtype)
                                    for v in leaves])
 
 
-def _packed_carrier(col, n: int, nw: int, loop=jax.lax.fori_loop):
+def _vmem_loop(lo, hi, body, init):
+    """``fori_loop`` whose state lives in VMEM scratch."""
+    return _in_vmem(lambda get, put: jax.lax.fori_loop(
+        lo, hi, lambda i, _: put(body(i, get())), 0), init)
+
+
+def _vmem_repeat(body, init):
+    """:func:`_repeat` with the state in VMEM scratch and ``more`` in the
+    carry (an int32 scalar): in interpret mode a condition that read the
+    scratch never saw the body's writes, and the loop did not end."""
+    def loop(get, put):
+        def step(_):
+            st, more = body(get())
+            put(st)
+            return more.astype(jnp.int32)
+        jax.lax.while_loop(lambda more: more > 0, step, jnp.int32(1))
+    return _in_vmem(loop, init)
+
+
+def _packed_carrier(col, n: int, nw: int, vmem: bool = False):
     """Lane-packed masks: ``(TB, W)`` uint32 words, element ``j`` in bit
-    ``j % 32`` of word ``j // 32``; positions in the ``(TB, 32, W)`` layout."""
+    ``j % 32`` of word ``j // 32``; positions in the ``(TB, 32, W)`` layout.
+    ``vmem`` keeps the loops' state in VMEM scratch (the Pallas kernel)."""
     i = jax.lax.broadcasted_iota(jnp.int32, (1, nw), 1)
     cnt = jnp.clip(n - LANE * i, 0, LANE)
     valid_w = jnp.where(cnt >= LANE, jnp.uint32(0xFFFFFFFF),
@@ -222,7 +257,8 @@ def _packed_carrier(col, n: int, nw: int, loop=jax.lax.fori_loop):
         col=col,
         unsorted=lambda s: ~s & valid_w,
         count=lambda m: jnp.sum(popc(m), axis=-1, keepdims=True),
-        drain=drain, loop=loop)
+        drain=drain, **(dict(loop=_vmem_loop, repeat=_vmem_repeat)
+                        if vmem else {}))
 
 
 def _dense_carrier(u):
@@ -245,8 +281,9 @@ def _dense_carrier(u):
 
 
 def _traverse_planes(car, alive, start, fresh, sigs, masks, s_top, crs, *,
-                     w, k, tb, fuse, gate):
-    """§III plane traversal from plane ``start`` down (one CR per plane).
+                     w, k, tb, fuse, gate, first=0):
+    """§III plane traversal from plane ``start`` down (one CR per plane),
+    over the plane blocks from ``first`` on.
 
     Planes are walked in blocks of ``fuse``.  Within a block, plane ``i``'s
     saw-a-1/saw-a-0 pair is precomputed under every combination of the
@@ -259,7 +296,6 @@ def _traverse_planes(car, alive, start, fresh, sigs, masks, s_top, crs, *,
     in tests/test_bankmesh.py); ``fuse=1`` degenerates to the classic
     one-round-per-plane walk with an identical collective payload.
     """
-    start = jnp.where(start == -2, s_top, start)          # fresh rows
     nblocks = -(-w // fuse)
 
     def block(bi, carry):
@@ -308,21 +344,36 @@ def _traverse_planes(car, alive, start, fresh, sigs, masks, s_top, crs, *,
         return alive, sigs, masks, s_top, seen, crs
 
     init = (alive, sigs, masks, s_top, jnp.zeros((tb, 1), jnp.int32), crs)
-    out = car.loop(0, nblocks, block, init)
+    out = car.loop(first, nblocks, block, init)
     return out[0], out[1], out[2], out[3], out[5]
 
 
-def _run_machine(car, tb: int, w: int, k: int, stop: int, gate,
+def _run_machine(car, tb: int, w: int, k: int, stop: int, gate=None,
                  drain_counts=None, fuse: int = 1, vary=None):
     """The §III machine over one mask carrier.
 
-    Returns ``(sorted_mask, positions, crs (TB, 1), drains (TB, 1))`` in the
-    carrier's own layout; a table slot with ``sig < 0`` is empty."""
+    With no cross-bank ``gate`` (one bank) the loops follow the tile's own
+    state: each traversal starts at the plane block of the highest start
+    plane among the rows still draining (every plane above it is inactive
+    for each of them, and the drained rows' results are discarded, so the
+    skipped blocks change nothing), and the machine stops once every row
+    has drained.  One reduction per iteration bounds both loops.  With a
+    gate every bank must join every collective round, so the loops keep
+    their fixed ``stop`` x ``ceil(w / fuse)`` trip counts.
+
+    Returns ``(sorted_mask, positions, crs (TB, 1), drains (TB, 1), steps
+    (TB, 1))`` in the carrier's own layout, ``steps`` being the plane steps
+    walked (the same in every row); a table slot with ``sig < 0`` is
+    empty."""
+    bounded = gate is None
+    if bounded:
+        gate = lambda cols: cols
     if drain_counts is None:
         drain_counts = lambda m: (m, jnp.zeros_like(m))
     if vary is None:
         vary = lambda x: x
     kk = max(1, k)
+    nblocks = -(-w // fuse)
 
     def load(sorted_m, sigs, masks):
         unsorted = car.unsorted(sorted_m)
@@ -340,14 +391,23 @@ def _run_machine(car, tb: int, w: int, k: int, stop: int, gate,
             kept.append(jnp.where(seen, s, -1))
         return alive, start, ~seen, tuple(kept)
 
-    def body(i, st):
-        sorted_m, sigs, masks, s_top, pos, count, crs, drains = st
+    def body(st):
+        sorted_m, sigs, masks, s_top, pos, count, crs, drains, steps = st
         done = count >= stop                                   # (TB, 1)
         alive, start, fresh, sigs = load(sorted_m, sigs, masks)
+        start = jnp.where(start == -2, s_top, start)          # fresh rows
+        first, more = 0, None
+        if bounded:
+            # the highest start plane of a pending row, plus 2; 0 once every
+            # row has drained, which ends the loop after this no-op pass
+            key = jnp.max(jnp.where(done, 0, start + 2))
+            more = key > 0
+            top = jnp.int32(w + 1) - key          # (w - 1) - highest start
+            first = jnp.where(more, top // fuse if fuse > 1 else top, nblocks)
         alive, sigs, masks, s_top, crs2 = _traverse_planes(
             car, alive, start, fresh, sigs, masks, s_top,
             jnp.zeros((tb, 1), jnp.int32), w=w, k=k, tb=tb, fuse=fuse,
-            gate=gate)
+            gate=gate, first=first)
         # rows already finished must not mutate state or counters
         alive = jnp.where(done, jnp.zeros_like(alive), alive)
         crs = crs + jnp.where(done, 0, crs2)
@@ -356,7 +416,8 @@ def _run_machine(car, tb: int, w: int, k: int, stop: int, gate,
         m_eff = jnp.minimum(m_tot, stop - count)
         keep, pos = car.drain(alive, before, m_eff, count, pos)
         return (sorted_m | keep, sigs, masks, s_top, pos, count + m_eff, crs,
-                drains + jnp.maximum(m_eff - 1, 0))
+                drains + jnp.maximum(m_eff - 1, 0),
+                steps + (nblocks - first) * fuse), more
 
     empty = vary(car.zeros(tb))
     row0 = jnp.zeros((tb, 1), jnp.int32)
@@ -364,32 +425,45 @@ def _run_machine(car, tb: int, w: int, k: int, stop: int, gate,
            (jnp.full((tb, 1), -1, jnp.int32),) * kk,  # table sigs (-1 empty)
            (empty,) * kk,                             # table masks
            jnp.full((tb, 1), w - 1, jnp.int32),       # s_top
-           vary(car.pos_zeros(tb)), row0, row0, row0)  # pos, count, crs, drains
-    st = car.loop(0, stop, body, st0)
-    sorted_m, _, _, _, pos, _, crs, drains = st
-    return sorted_m, pos, crs, drains
+           vary(car.pos_zeros(tb)),                   # pos
+           row0, row0, row0, row0)                    # count, crs, drains, steps
+    if bounded:       # every iteration drains >= 1 element of a pending row
+        st = car.repeat(body, st0)
+    else:
+        st = car.loop(0, stop, lambda i, st: body(st)[0], st0)
+    sorted_m, _, _, _, pos, _, crs, drains, steps = st
+    return sorted_m, pos, crs, drains, steps
 
 
 def _sort_kernel(w: int, k: int, stop: int, carrier, drained,
-                 in_ref, pos_ref, crs_ref, cyc_ref):
-    sorted_m, pos, crs, drains = _run_machine(
-        carrier(in_ref), pos_ref.shape[0], w, k, stop, lambda cols: cols)
+                 in_ref, pos_ref, tel_ref, cyc_ref):
+    tb = pos_ref.shape[0]
+    sorted_m, pos, crs, drains, steps = _run_machine(
+        carrier(in_ref), tb, w, k, stop)
     # undrained elements (early exit) get position `stop`: dropped later
     pos_ref[...] = jnp.where(drained(sorted_m), pos, stop)
-    crs_ref[...] = crs
+    # telemetry: per-row CRs, and the program's plane steps in its first row
+    first_row = jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0) == 0
+    tel_ref[:, 0:1] = crs
+    tel_ref[:, 1:2] = jnp.where(first_row, steps, 0)
     cyc_ref[...] = crs + drains
 
 
 @functools.partial(jax.jit,
                    static_argnames=("w", "k", "tb", "interpret", "stop_after",
-                                    "packed"))
+                                    "packed", "plane_steps"))
 def sort_pallas(x: jax.Array, w: int = 32, k: int = 2, tb: int = TB,
                 interpret: bool | None = None, stop_after: int | None = None,
-                packed: bool = True):
+                packed: bool = True, plane_steps: bool = False):
     """Sort rows of ``x`` (B, N) uint32 ascending; returns
     (values, order, column_reads, cycles) with per-row telemetry.
     ``stop_after`` is the per-row k-early-exit drain (outputs (B, stop));
     ``packed=False`` selects the dense-boolean equivalence baseline.
+    ``plane_steps=True`` returns column_reads as (B, 2): column 0 the
+    per-row CRs, column 1 the plane steps each ``tb``-row program walked,
+    in the program's first row (0 in its others), against the fixed
+    loop's ``w * stop`` — one device array, so reading it back costs no
+    extra copy.
     ``interpret=None`` resolves from the platform (compiled on TPU)."""
     interpret = resolve_interpret(interpret)
     if not (packed or interpret):
@@ -411,19 +485,20 @@ def sort_pallas(x: jax.Array, w: int = 32, k: int = 2, tb: int = TB,
         arg, pos_blk = pack_planes(x, w), (tb, LANE, nw)
         in_spec = pl.BlockSpec((w, tb, nw), lambda i: (0, i, 0))
         carrier = lambda ref: _packed_carrier(lambda s: ref[s], n, nw,
-                                              _vmem_loop)
+                                              vmem=True)
         drained = lambda sorted_w: _unpack_words3(sorted_w) == 1
     else:
         arg, pos_blk, in_spec = x, (tb, n), rows(tb, n)
         carrier = lambda ref: _dense_carrier(ref[...])
         drained = lambda sorted_m: sorted_m
-    pos, crs, cyc = pl.pallas_call(
+    pos, tel, cyc = pl.pallas_call(
         functools.partial(_sort_kernel, w, k, stop, carrier, drained),
         grid=(bp // tb,),
         in_specs=[in_spec],
-        out_specs=[rows(*pos_blk), rows(tb, 1), rows(tb, 1)],
-        out_shape=[jax.ShapeDtypeStruct((bp,) + pos_blk[1:], jnp.int32)]
-        + [jax.ShapeDtypeStruct((bp, 1), jnp.int32)] * 2,
+        out_specs=[rows(*pos_blk), rows(tb, 2), rows(tb, 1)],
+        out_shape=[jax.ShapeDtypeStruct((bp,) + pos_blk[1:], jnp.int32),
+                   jax.ShapeDtypeStruct((bp, 2), jnp.int32),
+                   jax.ShapeDtypeStruct((bp, 1), jnp.int32)],
         interpret=interpret,
     )(arg)
     if packed:
@@ -434,4 +509,5 @@ def sort_pallas(x: jax.Array, w: int = 32, k: int = 2, tb: int = TB,
     order = jnp.zeros((bp, stop), jnp.int32).at[row_ix, pos].set(
         cols, mode="drop")
     vals = jnp.take_along_axis(x, order, axis=1)
-    return vals[:b], order[:b], crs[:b, 0], cyc[:b, 0]
+    return (vals[:b], order[:b], tel[:b] if plane_steps else tel[:b, 0],
+            cyc[:b, 0])

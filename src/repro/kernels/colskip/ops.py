@@ -26,7 +26,7 @@ def colskip_sort_batched(x, w: int = 32, k: int = 2, *,
                          use_pallas: bool | None = None,
                          interpret: bool | None = None,
                          stop_after: int | None = None,
-                         packed: bool = True):
+                         packed: bool = True, plane_steps: bool = False):
     """Sort rows of ``x`` (B, N) uint32; returns (values, order, CRs, cycles).
 
     CR/cycle telemetry is the paper's latency metric (fed to the cost model).
@@ -35,9 +35,14 @@ def colskip_sort_batched(x, w: int = 32, k: int = 2, *,
     cover only the executed iterations (the k-min serving mode).
     ``packed=False`` selects the dense-boolean machine (equivalence
     baseline) instead of the lane-packed hot path.
+    ``plane_steps=True`` (the kernel only) also returns the plane steps the
+    kernel walked, as a second column of the CRs (see ``sort_pallas``).
     """
     use_pallas, interpret, _ = resolve_colskip(use_pallas, interpret, packed)
     if use_pallas:
         return _k.sort_pallas(x, w, k, interpret=interpret,
-                              stop_after=stop_after, packed=packed)
+                              stop_after=stop_after, packed=packed,
+                              plane_steps=plane_steps)
+    if plane_steps:
+        raise ValueError("only the Pallas kernel counts plane steps")
     return _ref.sort_ref(x, w, k, stop_after=stop_after, packed=packed)

@@ -330,6 +330,9 @@ def capture(engine, source: str | None = None,
     # direct f-strings below keep a scrape inside the export-overhead gate
     c[PREFIX + "requests_total"] = agg["requests"]
     c[PREFIX + "column_reads_total"] = agg["column_reads"]
+    c[PREFIX + "colskip_plane_steps_total"] = agg["colskip_plane_steps"]["run"]
+    c[PREFIX + "colskip_plane_slots_total"] = \
+        agg["colskip_plane_steps"]["slots"]
     c[PREFIX + "cycles_exact_total"] = agg["cycles_exact"]
     c[PREFIX + "cycles_estimated_total"] = agg["cycles_estimated"]
     c[PREFIX + "verify_failures_total"] = agg["verify_failures"]

@@ -247,14 +247,15 @@ def _round_trip(fn, tile: Tile, staged=None) -> tuple:
 def _compiled_colskip(b: int, n: int, w: int, state_k: int,
                       stop: int | None, use_pallas: bool,
                       interpret: bool, packed: bool):
-    """Warm executor for one colskip tile signature."""
+    """Warm executor for one colskip tile signature.  The Pallas kernel's
+    executor returns its CRs as (B, 2), the plane steps in column 1."""
     from repro.kernels.colskip import colskip_sort_batched
 
     key = ("colskip", b, n, w, state_k, stop, use_pallas, interpret, packed)
     return EXECUTOR_CACHE.get(key, lambda: _aot_compile(    # -> (fn, warm)
         "colskip", lambda x: colskip_sort_batched(
             x, w, state_k, use_pallas=use_pallas, interpret=interpret,
-            stop_after=stop, packed=packed), b, n))
+            stop_after=stop, packed=packed, plane_steps=use_pallas), b, n))
 
 
 @dataclass
@@ -392,13 +393,19 @@ class ColskipBackend(Backend):
                                      self.use_pallas, self.interpret,
                                      self.packed)
         vals, order, crs, cycles = _round_trip(fn, tile)
+        meta = {"w": self.w, "state_k": self.state_k, "stop_after": stop,
+                "packed": self.packed, "impl": self.impl, "exec_warm": warm}
+        if self.use_pallas:
+            # plane steps the kernel walked, against its fixed w x stop per
+            # TB-row program
+            from repro.kernels.colskip.kernel import TB
+            crs, steps = crs[:, 0], crs[:, 1]
+            meta["plane_steps"] = {
+                "run": int(steps.sum()),
+                "slots": -(-b // TB) * self.w * min(stop or n, n)}
         return TileResult(vals, np.asarray(order, np.int32),
                           np.asarray(crs, np.int64), np.asarray(cycles, np.int64),
-                          self.name, meta={"w": self.w, "state_k": self.state_k,
-                                           "stop_after": stop,
-                                           "packed": self.packed,
-                                           "impl": self.impl,
-                                           "exec_warm": warm})
+                          self.name, meta=meta)
 
     def warm(self, b: int, n: int, op: str, k: int | None) -> bool:
         stop = k if op == "kmin" else None

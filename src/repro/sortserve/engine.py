@@ -278,6 +278,9 @@ class SortServeEngine:
             # wall seconds from each request's feed to the launch of the
             # tile that served it, summed, and the requests counted
             "queue_wait_s": 0.0, "queue_waits": 0,
+            # plane steps the colskip kernel walked, and the w x stop a
+            # fixed loop would walk (the skip share is 1 - run / slots)
+            "colskip_plane_steps": {"run": 0, "slots": 0},
             "per_backend": {}, "per_op": {}, "modeled_hw": {},
             # mesh collective-round accounting (§IV manager rounds; the
             # mesh-side CR analogue): fixed shape, zeros off the mesh path.
@@ -582,6 +585,10 @@ class SortServeEngine:
             self._agg["column_reads"] += int(result.column_reads.sum())
         if result.cycles is not None:
             self._agg["cycles_exact"] += int(result.cycles.sum())
+        steps = result.meta.get("plane_steps")
+        if steps is not None:
+            for key in ("run", "slots"):
+                self._agg["colskip_plane_steps"][key] += steps[key]
         if result.estimated_cycles is not None:
             self._agg["cycles_estimated"] += float(result.estimated_cycles)
         # mesh collective rounds (zero off the mesh path): issued vs the
@@ -709,6 +716,7 @@ class SortServeEngine:
             # cycles): the sum and the requests it covers
             "queue_wait_s": {"sum": self._agg["queue_wait_s"],
                              "count": self._agg["queue_waits"]},
+            "colskip_plane_steps": dict(self._agg["colskip_plane_steps"]),
             "column_reads": self._agg["column_reads"],
             "cycles_exact": self._agg["cycles_exact"],
             "cycles_estimated": self._agg["cycles_estimated"],

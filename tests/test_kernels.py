@@ -5,9 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from test_packed_machine import BOUNDED_LOOP_CASES, bounded_loop_tile
 
 from repro.core import colskip_sort
 from repro.kernels.colskip import colskip_sort_batched
+from repro.kernels.colskip.kernel import TB
 from repro.kernels.colskip.ref import sort_ref
 from repro.kernels.radix_topk import radix_topk, radix_topk_threshold
 from repro.kernels.radix_topk.ref import threshold_ref
@@ -87,6 +89,73 @@ def test_colskip_kernel_vs_ref_and_hardware(b, n, w, k):
         assert np.array_equal(np.asarray(v1[r]), hw.values.astype(np.uint32))
         assert int(c1[r]) == hw.column_reads
         assert int(y1[r]) == hw.cycles
+
+
+def _plane_step_recount(x, w, k, stop, tb=TB):
+    """Per program: the number of iterations, and the sum over them of the
+    highest start plane among the program's unfinished rows, plus one —
+    from the numpy machine's own per-iteration starts, with the kernel's
+    zero rows of padding."""
+    bp = -(-len(x) // tb) * tb
+    x = np.pad(x, ((0, bp - len(x)), (0, 0)))
+    starts = [colskip_sort(r.astype(np.uint64), w, k, stop_after=stop)
+              .meta["starts"] for r in x]
+    out = []
+    for p in range(0, bp, tb):
+        prog = starts[p:p + tb]
+        iters = max(map(len, prog))
+        out.append((iters, sum(max(s[i] for s in prog if len(s) > i) + 1
+                               for i in range(iters))))
+    return out
+
+
+@pytest.mark.parametrize("case", BOUNDED_LOOP_CASES)
+def test_colskip_plane_steps_equal_numpy_recount(case):
+    """The kernel's plane-step count is the walk the tile's state allows:
+    each program's count equals the numpy recount, rides in its first row
+    beside unchanged CRs, and the iteration loop ends when the slowest row
+    has drained."""
+    x, w, k, stop = bounded_loop_tile(case)
+    _, _, tel, _ = colskip_sort_batched(
+        jnp.asarray(x), w, k, use_pallas=True, interpret=True,
+        stop_after=stop, plane_steps=True)
+    tel = np.asarray(tel)
+    assert tel.shape == (len(x), 2)
+    _, _, crs, _ = sort_ref(jnp.asarray(x), w, k, stop_after=stop)
+    assert np.array_equal(tel[:, 0], np.asarray(crs))
+    recount = _plane_step_recount(x, w, k, stop)
+    firsts = np.arange(len(x)) % TB == 0
+    assert list(tel[firsts, 1]) == [steps for _, steps in recount]
+    assert not tel[~firsts, 1].any()
+    stop_eff = stop or x.shape[1]
+    for iters, steps in recount:
+        assert steps <= iters * w and iters <= stop_eff
+    if case == "mapreduce":
+        assert recount[0][0] < stop_eff       # duplicates end it early
+
+
+@pytest.mark.parametrize("kinds,lo,hi", [
+    (("uniform", "normal") * 4, 0.97, 1.0),   # the .uniform cell's rows
+    (("kruskal",) * 8, 0.0, 0.6)], ids=["uniform-normal", "kruskal"])
+def test_colskip_plane_steps_follow_the_data(kinds, lo, hi):
+    """Uniform data leaves little to skip (run within 3% of the fixed
+    loop's slots); Kruskal edge weights, with their low ``s_top``, skip
+    over 40% of the plane steps."""
+    from repro.core.datasets import make_dataset
+    n = 128
+    x = np.stack([make_dataset(kind, n, 32, seed=70 + i)
+                  for i, kind in enumerate(kinds)]).astype(np.uint32)
+    _, _, tel, _ = colskip_sort_batched(jnp.asarray(x), 32, 2,
+                                        use_pallas=True, interpret=True,
+                                        plane_steps=True)
+    run, slots = int(np.asarray(tel)[:, 1].sum()), 32 * n
+    assert lo * slots <= run <= hi * slots, run / slots
+
+
+def test_colskip_plane_steps_only_on_the_kernel():
+    with pytest.raises(ValueError, match="plane steps"):
+        colskip_sort_batched(jnp.zeros((2, 8), jnp.uint32), 8, 2,
+                             use_pallas=False, plane_steps=True)
 
 
 def test_colskip_kernel_batch_padding():

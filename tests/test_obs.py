@@ -394,6 +394,31 @@ def test_queue_wait_is_exact_wall_time_under_a_fake_clock(load):
     assert counters["sortserve_queue_waits_total"] == want_count
 
 
+# ------------------------------------------ colskip plane-step counter
+def test_colskip_plane_steps_are_counted_exported_and_fetched_in_four(
+        tmp_path):
+    """Tiles on the colskip kernel add the plane steps they walked and the
+    fixed loop's slots to the engine's counter and its OpenMetrics export,
+    and the count rides in an output the tile already fetched: four
+    arrays, as before."""
+    from repro.core.datasets import make_dataset
+    eng = make_engine(FakeClock(), backends=("colskip",), use_pallas=True,
+                      interpret=True)
+    reqs = [SortRequest(op="sort", payload=make_dataset(
+        "kruskal", 64, 32, seed=i).astype(np.uint32)) for i in range(8)]
+    spans = _profiled_spans(tmp_path, lambda: eng.submit(reqs))
+    telem = eng.telemetry()
+    steps, tiles = telem["colskip_plane_steps"], telem["batcher"]["tiles"]
+    # four-row tiles, one kernel program each, w = 32, a full sort of 64
+    assert steps["slots"] == tiles * 32 * 64
+    assert 0 < steps["run"] < 0.6 * steps["slots"]
+    counters = eng.telemetry_snapshot().counters
+    assert counters["sortserve_colskip_plane_steps_total"] == steps["run"]
+    assert counters["sortserve_colskip_plane_slots_total"] == steps["slots"]
+    fetches = [sp[3] for sp in spans if sp[0] == "sortserve.execute.fetch"]
+    assert len(fetches) == tiles and all(f["arrays"] == 4 for f in fetches)
+
+
 # ------------------------------------------------------- chrome trace JSON
 def test_export_is_valid_chrome_trace():
     eng, tracer = traced_engine(FakeClock())

@@ -120,6 +120,63 @@ def test_property_packed_equals_dense_pallas_kernel(kind, n, k, stop_mode, seed)
         assert np.array_equal(np.asarray(a), np.asarray(b)), (field, kind)
 
 
+# ------------------------------------------ bounded loops: tiles that skip
+def _paper_rows(kinds, n: int, seed: int) -> np.ndarray:
+    from repro.core.datasets import make_dataset
+    return np.stack([make_dataset(kind, n, 32, seed=seed + i)
+                     for i, kind in enumerate(kinds)]).astype(np.uint32)
+
+
+def bounded_loop_tile(case: str):
+    """``(rows, w, k, stop)``: one tile whose rows finish at different
+    iterations and resume at different planes, so the kernel's plane loop
+    starts below the MSB and its iteration loop ends before ``stop``."""
+    skew = ("kruskal", "mapreduce") * 4                  # low s_top
+    if case == "kruskal-mapreduce":
+        return _paper_rows(skew, 48, 0), 32, 2, None
+    if case == "k0":
+        return _paper_rows(skew, 40, 10), 32, 0, None
+    if case == "mapreduce":          # every row drains duplicates: < n iterations
+        return _paper_rows(("mapreduce",) * 8, 64, 60), 32, 2, None
+    if case == "equal-beside-uniform":
+        rows = _paper_rows(("uniform",) * 2, 64, 20)
+        rows[0] = 123456789
+        return rows, 32, 2, None
+    if case == "all-ones":
+        rows = _paper_rows(("kruskal", "uniform", "mapreduce"), 40, 30)
+        rows[1] = 0xFFFFFFFF
+        return rows, 32, 2, None
+    if case in ("kmin-1", "kmin-7"):
+        return _paper_rows(skew, 64, 40), 32, 2, int(case[5:])
+    if case == "zero-padded":                # 5 rows: 3 zero rows of padding
+        return _paper_rows(("kruskal", "normal", "mapreduce", "kruskal",
+                            "uniform"), 33, 50), 32, 2, None
+    raise ValueError(case)
+
+
+BOUNDED_LOOP_CASES = ("kruskal-mapreduce", "k0", "mapreduce",
+                      "equal-beside-uniform",
+                      "all-ones", "kmin-1", "kmin-7", "zero-padded")
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "dense"])
+@pytest.mark.parametrize("case", BOUNDED_LOOP_CASES)
+def test_bounded_loop_kernel_equals_numpy_machine(case, packed):
+    """The kernel's state-bounded plane and iteration loops are exact:
+    values, order, CRs and cycles equal the numpy machine's, row by row,
+    on both carriers."""
+    x, w, k, stop = bounded_loop_tile(case)
+    vals, order, crs, cyc = colskip_sort_batched(
+        jnp.asarray(x), w, k, use_pallas=True, interpret=True,
+        stop_after=stop, packed=packed)
+    for r, row in enumerate(x):
+        hw = colskip_sort(row.astype(np.uint64), w, k, stop_after=stop)
+        assert np.array_equal(np.asarray(vals[r]), hw.values.astype(np.uint32))
+        assert np.array_equal(np.asarray(order[r]), hw.order), (case, r)
+        assert int(crs[r]) == hw.column_reads, (case, r)
+        assert int(cyc[r]) == hw.cycles, (case, r)
+
+
 @pytest.mark.parametrize("kind", DATASETS)
 def test_packed_mesh_matches_dense_local(kind):
     """§V.C invariance holds for the packed carrier on a (1+-device) mesh."""

@@ -53,12 +53,14 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("n,stop", [(64, None), (128, None), (1024, None),
-                                    (4096, None), (1024, 16)])
-def test_colskip_kernel_compiles_for_v5e(one_chip, n, stop):
+@pytest.mark.parametrize("n,stop,k", [
+    pytest.param(n, stop, 2, id=f"{n}-{stop}")
+    for n, stop in ((64, None), (128, None), (1024, None), (4096, None),
+                    (1024, 16))] + [pytest.param(1024, None, 0, id="1024-k0")])
+def test_colskip_kernel_compiles_for_v5e(one_chip, n, stop, k):
     from repro.kernels.colskip.kernel import sort_pallas
     x = jax.ShapeDtypeStruct((8, n), jnp.uint32, sharding=one_chip)
-    hlo = _compile(lambda a: sort_pallas(a, interpret=False,
+    hlo = _compile(lambda a: sort_pallas(a, k=k, interpret=False,
                                          stop_after=stop), x)
     assert "tpu_custom_call" in hlo
 
