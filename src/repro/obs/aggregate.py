@@ -333,6 +333,8 @@ def capture(engine, source: str | None = None,
     c[PREFIX + "colskip_plane_steps_total"] = agg["colskip_plane_steps"]["run"]
     c[PREFIX + "colskip_plane_slots_total"] = \
         agg["colskip_plane_steps"]["slots"]
+    c[PREFIX + "readback_tiles_total"] = agg["readback"]["tiles"]
+    c[PREFIX + "readback_copies_total"] = agg["readback"]["copies"]
     c[PREFIX + "cycles_exact_total"] = agg["cycles_exact"]
     c[PREFIX + "cycles_estimated_total"] = agg["cycles_estimated"]
     c[PREFIX + "verify_failures_total"] = agg["verify_failures"]

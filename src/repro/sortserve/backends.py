@@ -37,13 +37,14 @@ cap (the ROADMAP's adaptive cost policy; the §V model keeps supplying
 hardware-cycle telemetry either way).
 
 Execution itself runs through a process-level :class:`ExecutorCache` of
-AOT-compiled tile executors keyed by ``(backend, B, N, k, flags)`` with
-donated input buffers — a tile whose signature was seen before skips
-tracing/lowering entirely and goes straight to the warm executable.  Each
-executor carries its backend's name (HLO module ``jit_<name>``, ops under
-the ``<name>`` scope), and every device backend runs a tile through one
-round trip, :func:`_round_trip` — put, launch, wait, fetch — each step a
-profiler span (:func:`repro.obs.tracer.span`).
+AOT-compiled tile executors keyed by ``(backend, B, N, k, flags)`` — a
+tile whose signature was seen before skips tracing/lowering entirely and
+goes straight to the warm executable.  Each executor carries its
+backend's name (HLO module ``jit_<name>``, ops under the ``<name>``
+scope) and returns several 32-bit outputs packed in one buffer, and every
+device backend runs a tile through one round trip, :func:`_round_trip` —
+put, launch, wait, fetch — each step a profiler span
+(:func:`repro.obs.tracer.span`).
 """
 
 from __future__ import annotations
@@ -78,8 +79,8 @@ class ExecutorCache:
     """Process-level cache of AOT-compiled tile executors.
 
     Keys are full tile signatures — ``(backend, B, N, k/stop, flags...)`` —
-    and values are ``jax.jit(...).lower(...).compile()`` executables with
-    the tile buffer donated, so a warm hit pays neither tracing nor
+    and values are ``jax.jit(...).lower(...).compile()`` executables
+    (:func:`_aot_compile`), so a warm hit pays neither tracing nor
     lowering nor dispatch-cache hashing.  The cache is process-global on
     purpose: engines come and go (benchmarks build them per pass) but
     compiled executables are reusable across all of them, exactly like the
@@ -199,35 +200,76 @@ def checkout_cache_dir() -> str:
     return str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
-def _aot_compile(name: str, body, b: int, n: int, *,
-                 donate_first: bool = True):
+class _Executor:
+    """A compiled tile executor and where its outputs lie in the one
+    buffer it returns.
+
+    ``layout`` holds ``(shape, dtype, start, stop)`` for each output, as
+    uint32 words of the packed buffer, or is None when the executor
+    returns its outputs as they are.  Every other attribute is the
+    compiled executable's (``as_text``, ``cost_analysis``, ...)."""
+
+    __slots__ = ("compiled", "layout")
+
+    def __init__(self, compiled, layout):
+        self.compiled = compiled
+        self.layout = layout
+
+    def __call__(self, x):
+        return self.compiled(x)
+
+    def __getattr__(self, name):
+        return getattr(self.compiled, name)
+
+
+def _aot_compile(name: str, body, b: int, n: int) -> _Executor:
     """The executor ``name`` for a ``(b, n)`` uint32 tile:
-    ``jax.jit(body).lower(...).compile()`` with the tile donated.
+    ``jax.jit(body).lower(...).compile()``.
 
     The jitted function is called ``name`` and its body runs under the
     ``name`` scope, so the HLO module is ``jit_<name>`` and a device trace
-    can tell the executors apart.  Donation is skipped on CPU, where XLA
-    cannot reuse the buffers and would warn on every executable instead."""
+    can tell the executors apart.  A body with several outputs, all of
+    32-bit dtypes, returns them packed: each bitcast to uint32, flattened
+    and concatenated into one buffer, so that :func:`_round_trip` reads a
+    tile back in one device->host copy, each copy having a fixed delay
+    whatever its size.  The layout is recorded while the body is traced.
+    No output aliases the tile (a packed buffer is 1-D, the one
+    single-output body returns int32), so the tile is not donated."""
     import jax
     import jax.numpy as jnp
 
+    layout = []
+
     def fn(x):
         with jax.named_scope(name):
-            return body(x)
+            out = body(x)
+            if not isinstance(out, tuple) or len(out) < 2 or any(
+                    o.dtype.itemsize != 4 for o in out):
+                return out
+            start = 0
+            for o in out:
+                layout.append((o.shape, o.dtype, start, start + o.size))
+                start += o.size
+            return jnp.concatenate([
+                jax.lax.bitcast_convert_type(o, jnp.uint32).ravel()
+                for o in out])
 
     fn.__name__ = fn.__qualname__ = name
-    donate = (0,) if donate_first and jax.default_backend() != "cpu" else ()
-    return jax.jit(fn, donate_argnums=donate).lower(
+    compiled = jax.jit(fn).lower(
         jax.ShapeDtypeStruct((b, n), jnp.uint32)).compile()
+    return _Executor(compiled, tuple(layout) or None)
 
 
-def _round_trip(fn, tile: Tile, staged=None) -> tuple:
+def _round_trip(fn: _Executor, tile: Tile, staged=None) -> tuple:
     """Run one tile through its executor: the outputs as numpy arrays.
 
     Four steps, each a profiler span carrying the tile id: ``put`` copies
     the tile to the device (skipped when ``staged`` already holds it),
     ``launch`` enqueues the executor, ``wait`` blocks until the device is
-    done, and ``fetch`` copies every output back to the host."""
+    done, and ``fetch`` copies the outputs back to the host: one copy of a
+    packed executor's buffer, split into views by its layout, or one copy
+    per output.  The copies issued are left in
+    ``tile.obs["readback_copies"]`` for the engine's counter."""
     import jax
     import jax.numpy as jnp
 
@@ -240,8 +282,18 @@ def _round_trip(fn, tile: Tile, staged=None) -> tuple:
     outs = out if isinstance(out, tuple) else (out,)
     with span("sortserve.execute.wait", tile=seq):
         jax.block_until_ready(outs)
-    with span("sortserve.execute.fetch", tile=seq, arrays=len(outs)):
-        return tuple(np.asarray(o) for o in outs)
+    layout = fn.layout
+    with span("sortserve.execute.fetch", tile=seq,
+              arrays=len(outs) if layout is None else len(layout),
+              copies=len(outs)):
+        if layout is None:
+            host = tuple(np.asarray(o) for o in outs)
+        else:
+            flat = np.asarray(out)
+            host = tuple(flat[start:stop].view(dtype).reshape(shape)
+                         for shape, dtype, start, stop in layout)
+    tile.obs["readback_copies"] = len(outs)
+    return host
 
 
 def _compiled_colskip(b: int, n: int, w: int, state_k: int,
@@ -470,15 +522,13 @@ class ShardedColskipBackend(Backend):
         from repro.dist.bankmesh import sharded_tile_fn
         # AOT-compiled through the executor cache (like the local
         # backends), so a cold mesh tile is visible as a cache miss —
-        # the engine's warm-only EMA gate depends on that.  The tile is
-        # not donated: its bank shards cannot alias the replicated outputs
-        # (the TPU compiler refuses the alias)
+        # the engine's warm-only EMA gate depends on that
         return EXECUTOR_CACHE.get(self._mesh_key(b, n, stop_eff),
                                   lambda: _aot_compile(
             "colskip_mesh",
             sharded_tile_fn(self.mesh, self.axis_name, self.w,
                             self.state_k, stop_eff, self.packed, self.fuse),
-            b, n, donate_first=False))
+            b, n))
 
     def prefetch(self, tile: Tile) -> bool:
         """Stage the next tile's device transfer (double buffering).

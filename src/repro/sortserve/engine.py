@@ -281,6 +281,9 @@ class SortServeEngine:
             # plane steps the colskip kernel walked, and the w x stop a
             # fixed loop would walk (the skip share is 1 - run / slots)
             "colskip_plane_steps": {"run": 0, "slots": 0},
+            # tiles read back by the device backends' round trip, and the
+            # device->host copies it issued for them
+            "readback": {"tiles": 0, "copies": 0},
             "per_backend": {}, "per_op": {}, "modeled_hw": {},
             # mesh collective-round accounting (§IV manager rounds; the
             # mesh-side CR analogue): fixed shape, zeros off the mesh path.
@@ -524,6 +527,7 @@ class SortServeEngine:
         with span("sortserve.execute.run", tile=tile.obs.get("seq")):
             result = backend.run(tile)
         t1 = self._clock()
+        copies = tile.obs.pop("readback_copies", None)
         if faulty:
             # injection + verification guard, in virtual time, before any
             # telemetry accounting: a faulted execution contributes nothing
@@ -589,6 +593,9 @@ class SortServeEngine:
         if steps is not None:
             for key in ("run", "slots"):
                 self._agg["colskip_plane_steps"][key] += steps[key]
+        if copies is not None:
+            self._agg["readback"]["tiles"] += 1
+            self._agg["readback"]["copies"] += copies
         if result.estimated_cycles is not None:
             self._agg["cycles_estimated"] += float(result.estimated_cycles)
         # mesh collective rounds (zero off the mesh path): issued vs the
@@ -717,6 +724,7 @@ class SortServeEngine:
             "queue_wait_s": {"sum": self._agg["queue_wait_s"],
                              "count": self._agg["queue_waits"]},
             "colskip_plane_steps": dict(self._agg["colskip_plane_steps"]),
+            "readback": dict(self._agg["readback"]),
             "column_reads": self._agg["column_reads"],
             "cycles_exact": self._agg["cycles_exact"],
             "cycles_estimated": self._agg["cycles_estimated"],

@@ -480,22 +480,33 @@ def test_async_backoff_resubmits_shed_requests_until_served():
     no caller-visible RetryAfter, no silent drops."""
     import time
 
-    eng = make_engine(backends=("numpy",), tile_rows=2, banks=2, bank_rows=2,
+    clock = FakeClock()
+    eng = make_engine(clock=clock, backends=("numpy",), tile_rows=2,
+                      banks=2, bank_rows=2,
                       admission=WatermarkPolicy(high_watermark=1, shed=True,
                                                 retry_after_vt=50.0))
-    server = AsyncSortServe(eng, max_batch=16, max_wait_ms=50.0,
+    server = AsyncSortServe(eng, max_batch=16, max_wait_ms=50.0, clock=clock,
                             retry_policy=BackoffPolicy(base_s=1e-3,
                                                        cap_s=0.01,
                                                        max_attempts=12))
-    # six distinct widths: six open buckets that all age out together, so
-    # the collector dispatches them as ONE six-tile feed — with 2 banks and
-    # high_watermark=1 at least one tile is deterministically shed, and the
-    # shed requests ride the backoff schedule back in alone
+    # six distinct widths: six open buckets, all stamped at t=0, that age
+    # out together on one tick of the fake clock once the collector holds
+    # them all, so it dispatches them as ONE six-tile feed — with 2 banks
+    # and high_watermark=1 at least one tile is deterministically shed, and
+    # the shed requests ride the backoff schedule back in alone
     reqs = [SortRequest("sort", np.arange(w, dtype=np.uint32))
             for w in (8, 16, 32, 64, 128, 8)]
     futures = [server.submit(q) for q in reqs]
-    time.sleep(0.2)                     # let every bucket cross max_wait
-    got = [f.result(timeout=120) for f in futures]
+    give_up = time.monotonic() + 120
+    while server._q.qsize():            # the collector has taken all six
+        assert time.monotonic() < give_up
+        time.sleep(1e-3)
+    clock.tick(0.2)                     # every bucket crosses max_wait
+    while not all(f.done() for f in futures):
+        assert time.monotonic() < give_up, "shed requests never served"
+        time.sleep(2e-3)
+        clock.tick(0.01)                # the backoff schedule's clock
+    got = [f.result() for f in futures]
     server.close()
     assert all(check_against_oracle(q, r) for q, r in zip(reqs, got))
     # the engine really shed (so the backoff path ran), yet every caller

@@ -328,6 +328,7 @@ def test_profiler_spans_cover_every_tile_of_the_serving_path(tmp_path):
         fetch = next(sp for sp in steps if sp[0].endswith("fetch"))
         assert fetch[3]["arrays"] == {"colskip": 4, "radix_topk": 3,
                                       "jaxsort": 1}[stats["backend"]]
+        assert fetch[3]["copies"] == 1
         assert stats["rows"] == 4 and stats["tile"] in scattered
 
 
@@ -400,7 +401,8 @@ def test_colskip_plane_steps_are_counted_exported_and_fetched_in_four(
     """Tiles on the colskip kernel add the plane steps they walked and the
     fixed loop's slots to the engine's counter and its OpenMetrics export,
     and the count rides in an output the tile already fetched: four
-    arrays, as before."""
+    arrays, as before, read back in one device->host copy, which the
+    engine's readback counter and its export count."""
     from repro.core.datasets import make_dataset
     eng = make_engine(FakeClock(), backends=("colskip",), use_pallas=True,
                       interpret=True)
@@ -417,6 +419,10 @@ def test_colskip_plane_steps_are_counted_exported_and_fetched_in_four(
     assert counters["sortserve_colskip_plane_slots_total"] == steps["slots"]
     fetches = [sp[3] for sp in spans if sp[0] == "sortserve.execute.fetch"]
     assert len(fetches) == tiles and all(f["arrays"] == 4 for f in fetches)
+    assert all(f["copies"] == 1 for f in fetches)
+    assert telem["readback"] == {"tiles": tiles, "copies": tiles}
+    assert counters["sortserve_readback_tiles_total"] == tiles
+    assert counters["sortserve_readback_copies_total"] == tiles
 
 
 # ------------------------------------------------------- chrome trace JSON

@@ -730,3 +730,98 @@ def test_compile_cache_directory_follows_the_environment(tmp_path, env_dir):
                          text=True, timeout=300, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "OK" in out.stdout
+
+
+# ------------------------------------------------ one-copy packed read-back
+def _executor_case(case: str, b: int, n: int):
+    """``(executor, body, packs)`` for one case: the serving executor as
+    the backend builds it, the tile body it compiles, and whether its
+    outputs should come back packed in one buffer."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.colskip import colskip_sort_batched
+    from repro.sortserve import backends as bk
+
+    if case.startswith("colskip-"):
+        pallas, stop = "interpret" in case, (5 if "kmin" in case else None)
+        fn, _ = bk._compiled_colskip(b, n, 32, 2, stop, pallas, pallas, True)
+        return fn, lambda x: colskip_sort_batched(
+            x, 32, 2, use_pallas=pallas, interpret=pallas, stop_after=stop,
+            packed=True, plane_steps=pallas), True
+    if case.startswith("radix_topk-"):
+        kmin = case.endswith("kmin")
+        fn, _ = bk.RadixTopkBackend._executor(b, n, 3, kmin)
+        return fn, lambda x: bk._radix_select(x, 3, kmin), True
+    if case == "colskip_mesh":
+        from repro.dist.bankmesh import sharded_tile_fn
+        be = bk.ShardedColskipBackend()
+        assert be.n_devices == 4 and n % 4 == 0
+        fn, _ = be._mesh_executor(b, n, n)
+        return fn, sharded_tile_fn(be.mesh, be.axis_name, be.w, be.state_k,
+                                   n, be.packed, be.fuse), True
+    if case == "jaxsort":
+        fn, _ = bk.JaxSortBackend._executor(b, n)
+        return fn, lambda x: jnp.argsort(x, axis=-1, stable=True), False
+
+    def mixed(x):                       # float32 bits, NaN payloads included
+        return (x, (x >> 3).astype(jnp.int32),
+                jax.lax.bitcast_convert_type(x, jnp.float32), x[:, 0])
+
+    body = {"mixed-32bit": mixed,
+            "uint8-output": lambda x: (x, x.astype(jnp.uint8))}[case]
+    return bk._aot_compile(case, body, b, n), body, case == "mixed-32bit"
+
+
+def check_packed_round_trip(case: str, b: int = 4, n: int = 16) -> None:
+    """The round trip returns the tile body's own outputs bit for bit, with
+    their shapes and dtypes, in one device->host copy when they pack."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.sortserve.backends import _round_trip
+    from repro.sortserve.batcher import Tile
+
+    rng = np.random.default_rng(17)
+    data = rng.integers(0, 2**32, (b, n), dtype=np.uint32)
+    data[:, ::3] = data[:, :1]                       # ties
+    data[0, :4] = [0, 2**32 - 1, 0x7FC00001, 0xFF800000]
+    fn, body, packs = _executor_case(case, b, n)
+    tile = Tile(op="sort", data=data, k=None, entries=[], pad_rows=0)
+    got = _round_trip(fn, tile)
+    want = [np.asarray(o) for o in
+            jax.tree.leaves(jax.jit(body)(jnp.asarray(data)))]
+    assert (fn.layout is not None) is packs
+    assert tile.obs["readback_copies"] == (1 if packs else len(want))
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        assert g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize("case", [
+    "colskip-xla", "colskip-xla-kmin", "colskip-interpret",
+    "colskip-interpret-kmin", "radix_topk-topk", "radix_topk-kmin",
+    "colskip_mesh", "jaxsort", "mixed-32bit", "uint8-output"])
+def test_packed_round_trip_equals_unpacked_outputs(case):
+    """Multi-output executors of 32-bit outputs read a tile back in one
+    packed copy that splits into exactly the unpacked outputs; jaxsort's
+    single output and a body with a non-32-bit output stay unpacked.
+    The mesh executor runs on a forced four-device CPU mesh."""
+    if case != "colskip_mesh":
+        check_packed_round_trip(case)
+        return
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(here), "src"), here]))
+    code = ("from test_sortserve import check_packed_round_trip\n"
+            "check_packed_round_trip('colskip_mesh')\nprint('OK')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "OK" in out.stdout
